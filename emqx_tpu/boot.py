@@ -73,6 +73,11 @@ class Node:
         from .ops import speedups as _speedups
 
         _speedups.load()
+        # before the first compile: the engine warmup below compiles
+        # the whole shape ladder, which a warm cache reads back
+        from . import compile_cache
+
+        compile_cache.enable()
 
         # 1. broker core (+ caps from the mqtt zone config)
         from .broker.caps import MqttCaps
@@ -90,15 +95,18 @@ class Node:
 
             n_dp = cfg.get("parallel.dp")
             n_sub = cfg.get("parallel.sub") or None
-            n_dev = len(jax.devices())
-            if n_dev >= 2 and n_dev % n_dp == 0:
-                mesh = make_mesh(n_dp=n_dp, n_sub=n_sub)
-                log.info("parallel mesh: %s", dict(mesh.shape))
-            else:
-                log.warning(
-                    "parallel.enable set but %d device(s) don't fit "
-                    "dp=%d — running single-device", n_dev, n_dp,
+            devs = jax.devices()
+            want = n_dp * n_sub if n_sub else len(devs)
+            if want < 2 or len(devs) < want or want % n_dp:
+                # a mesh that does not fit must stop the boot: serving
+                # single-device instead would hide the missing chips
+                raise RuntimeError(
+                    f"parallel.enable needs a mesh of dp={n_dp} x "
+                    f"sub={n_sub or 'all'} (>= 2 devices), found "
+                    f"{len(devs)} {devs[0].platform} device(s)"
                 )
+            mesh = make_mesh(n_dp=n_dp, n_sub=n_sub, devices=devs[:want])
+            log.info("parallel mesh: %s", dict(mesh.shape))
         broker = ClusterBroker(
             shared_strategy=cfg.get("broker.shared_subscription_strategy"),
             mesh=mesh,

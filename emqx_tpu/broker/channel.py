@@ -10,6 +10,7 @@ feeds packets in; the channel returns packets to write out.
 
 from __future__ import annotations
 
+import asyncio
 import time
 from typing import List, Optional, Tuple
 
@@ -34,6 +35,7 @@ from .packet import (
     Will,
 )
 from .caps import CapError
+from .dispatch_engine import QueueDeadlineExceeded, QueueOverloadError
 from .pubsub import Broker, EXCLUSIVE_PREFIX, ExclusiveTaken
 from .session import Session, SessionConfig
 
@@ -42,6 +44,13 @@ class ProtocolError(Exception):
     def __init__(self, code: int, msg: str = ""):
         super().__init__(msg or hex(code))
         self.code = code
+
+
+def _retrieve(fut) -> None:
+    # a QoS0 publish has no one to tell: the engine already counted a
+    # shed or failed publish, so only mark the exception retrieved
+    if not fut.cancelled():
+        fut.exception()
 
 
 class Channel:
@@ -95,6 +104,10 @@ class Channel:
         # already ran the chain off-loop (covers filter rewrites);
         # consumed once by _handle_subscribe so the chain runs ONCE
         self.presub_filters = None
+        # (future, ack) of a PUBLISH handed to the broker's dispatch
+        # engine: whoever called handle_packet takes it (take_publish)
+        # and answers it once resolved
+        self.pending_publish = None
 
     # --- inbound dispatch -------------------------------------------------
 
@@ -364,27 +377,93 @@ class Channel:
             # reference's #message.headers), never the wire props
             headers={"username": self.username or "", "peerhost": self.peer},
         )
+        eng = self._publish_engine()
         if pkt.qos == 0:
-            self.broker.publish(msg)
+            if eng is None:
+                self.broker.publish(msg)
+            else:
+                self.pending_publish = (eng.submit(msg), None)
             return []
         if pkt.qos == 1:
-            n = self.broker.publish(msg)
-            code = 0 if n else RC.NO_MATCHING_SUBSCRIBERS
-            return [Puback(Type.PUBACK, pkt.packet_id, code if self.proto_ver == MQTT_V5 else 0)]
+            if eng is None:
+                return [self._publish_ack(
+                    Type.PUBACK, pkt.packet_id, self.broker.publish(msg)
+                )]
+            self.pending_publish = (eng.submit(msg), (Type.PUBACK, pkt.packet_id))
+            return []
         # QoS2: publish on first receipt, park until PUBREL
         assert self.session is not None
         try:
             fresh = self.session.await_rel(pkt.packet_id)
         except OverflowError:
             raise ProtocolError(RC.RECEIVE_MAXIMUM_EXCEEDED, "too many inflight QoS2")
-        code = 0
-        if fresh:
-            n = self.broker.publish(msg)
-            if not n and self.proto_ver == MQTT_V5:
-                code = RC.NO_MATCHING_SUBSCRIBERS
-        elif self.proto_ver == MQTT_V5:
-            code = RC.PACKET_IDENTIFIER_IN_USE
-        return [Puback(Type.PUBREC, pkt.packet_id, code)]
+        if not fresh:
+            code = RC.PACKET_IDENTIFIER_IN_USE if self.proto_ver == MQTT_V5 else 0
+            return [Puback(Type.PUBREC, pkt.packet_id, code)]
+        if eng is None:
+            return [self._publish_ack(
+                Type.PUBREC, pkt.packet_id, self.broker.publish(msg)
+            )]
+        self.pending_publish = (eng.submit(msg), (Type.PUBREC, pkt.packet_id))
+        return []
+
+    def _publish_engine(self):
+        """The dispatch engine a PUBLISH goes through, or None for the
+        synchronous host publish: no engine, an engine that stopped, or
+        an external tracer, whose spans wrap only that path. A traced
+        publish that could have used the engine is counted, so a node
+        with tracing on shows how much it served from the host trie."""
+        eng = self.broker.engine
+        if eng is None or eng.closed:
+            return None
+        if self.broker.tracer is not None:
+            eng.telemetry.count("traced_host_publish_total")
+            return None
+        return eng
+
+    def _publish_ack(self, typ: int, packet_id: int, n: int) -> Puback:
+        """PUBACK/PUBREC for a publish that reached `n` subscribers."""
+        code = 0 if n or self.proto_ver != MQTT_V5 else RC.NO_MATCHING_SUBSCRIBERS
+        return Puback(typ, packet_id, code)
+
+    def take_publish(self):
+        """Hand over `pending_publish`: None for QoS0 (nothing to
+        answer, not waited for), else (future, ack) for publish_ack()
+        once the future resolved. Clearing the slot lets streams that
+        share this channel each take their own publish."""
+        fut, ack = self.pending_publish
+        self.pending_publish = None
+        if ack is None:
+            fut.add_done_callback(_retrieve)
+            return None
+        return fut, ack
+
+    def publish_ack(self, fut, ack) -> List[object]:
+        """The packets answering a resolved QoS1/2 engine publish: the
+        PUBACK/PUBREC with the reason code from the delivery count. An
+        admission refusal answers quota exceeded (0x97) on v5 and
+        nothing on v3, as EMQX does on quota; a refused QoS2 is not
+        awaiting PUBREL, so a resend publishes. Any other failure
+        raises."""
+        typ, packet_id = ack
+        try:
+            n = fut.result()
+        except (QueueOverloadError, QueueDeadlineExceeded):
+            if typ == Type.PUBREC and self.session is not None:
+                self.session.release_rel(packet_id)
+            if self.proto_ver != MQTT_V5:
+                return []
+            return [Puback(typ, packet_id, RC.QUOTA_EXCEEDED)]
+        return [self._publish_ack(typ, packet_id, n)]
+
+    async def finish_publish(self) -> List[object]:
+        """Take and await `pending_publish`; returns the packets to send
+        (for a caller that keeps order by waiting, as a QUIC stream does)."""
+        p = self.take_publish()
+        if p is None:
+            return []
+        await asyncio.wait({p[0]})
+        return self.publish_ack(*p)
 
     # --- acks (outbound flow control) --------------------------------------
 

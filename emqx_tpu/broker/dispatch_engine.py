@@ -95,6 +95,7 @@ from __future__ import annotations
 
 import asyncio
 import contextlib
+import functools
 import gc
 import logging
 from collections import deque
@@ -232,6 +233,11 @@ class DispatchEngine:
         self.aot_warm = bool(aot_warm)
         self.gc_guard = bool(gc_guard)
         self.warmed = False
+        self.warmup_info: dict = {}
+        # router.shape_key() the shape ladder was last warmed for, and
+        # the off-loop re-warm in flight when the table outgrew it
+        self._warm_key = None
+        self._rewarm: Optional[asyncio.Future] = None
         # alarms/flight: explicit wiring wins; otherwise resolved
         # lazily through the attached sentinel (boot order attaches
         # the engine first and the obs bundle later — or vice versa in
@@ -316,9 +322,13 @@ class DispatchEngine:
              table/session bulk from inside a timed launch — paired
              with the per-flush collector pause in _flush/_collect_one.
 
-        Returns a summary dict (also merged into status())."""
+        Once warmed, the engine re-warms by itself whenever the table
+        outgrows the warmed shapes (_rewarm_pending), off the loop.
+
+        Returns a summary dict, kept as `warmup_info`."""
         router = self.router
         tel = self.telemetry
+        t_start = tel.clock()
         info: dict = {}
         chunk_kb = self.transfer_chunk_kb
         if not chunk_kb:
@@ -341,7 +351,10 @@ class DispatchEngine:
         info["transfer_chunk_kb"] = chunk_kb
         if self.aot_warm:
             try:
-                info["aot_shapes"] = router.warmup_shapes(self.queue_depth)
+                with tel.warming():
+                    info["aot_shapes"] = router.warmup_shapes(
+                        self.queue_depth
+                    )
             except Exception as e:
                 # a device that cannot even warm up is the breaker's
                 # business — boot comes up degraded, never dead
@@ -361,7 +374,92 @@ class DispatchEngine:
             gc.collect()
             gc.freeze()
         self.warmed = True
+        self._warm_key = router.shape_key()
+        info["seconds"] = tel.clock() - t_start
+        info["rewarms"] = 0
+        self.warmup_info = info
         return info
+
+    def _rewarm_pending(self) -> bool:
+        """True while the open batch must wait for an off-loop re-warm.
+
+        When the host tables outgrew the warmed shapes (the table grew,
+        a pattern class appeared), the first batch after that syncs
+        the device on the loop, which owns the host tables, and starts
+        the shape ladder's compile on a worker thread. The loop keeps
+        serving sockets meanwhile; the queued publishes launch when
+        the warm lands (_rewarm_done), so none compiles at serve time.
+        In-flight batches are collected first: the warm must be the
+        only device user while it runs."""
+        if self._rewarm is not None:
+            return True
+        router = self.router
+        if not (self.warmed and self.aot_warm) or router.device_suspended:
+            return False
+        if router.shape_key() == self._warm_key:
+            return False
+        while self._inflight:
+            self._collect_one()
+        if self._rewarm is not None:
+            return True  # a collect above re-entered _flush and began it
+        tel = self.telemetry
+        t0 = tel.clock()
+        dt = router.device_table
+        try:
+            # a full upload: the changed shapes need one anyway, and a
+            # scatter of an unwarmed batch count would compile here
+            dt.invalidate()
+            dt.sync()
+        except Exception:
+            # the launch below meets the same fault and fails over
+            tel.count("warmup_failures_total")
+            return False
+        key = router.shape_key()
+        info = self.warmup_info
+        info["rewarm_sync_seconds"] = (
+            info.get("rewarm_sync_seconds", 0.0) + tel.clock() - t0
+        )
+        try:
+            self._rewarm = asyncio.get_running_loop().run_in_executor(
+                None, self._rewarm_shapes
+            )
+        except RuntimeError:
+            return False  # the loop is shutting down: nothing to warm for
+        self._rewarm.add_done_callback(
+            functools.partial(self._rewarm_done, key, t0)
+        )
+        return True
+
+    def _rewarm_shapes(self) -> int:
+        """Worker thread: compile the ladder over the synced state."""
+        with self.telemetry.warming():
+            return self.router.warmup_shapes(self.queue_depth, sync=False)
+
+    def _rewarm_done(self, key, t0: float, fut: "asyncio.Future") -> None:
+        """Loop: account the re-warm, then launch what queued behind it."""
+        self._rewarm = None
+        self._warm_key = key
+        tel = self.telemetry
+        try:
+            shapes = fut.result()
+        except Exception as e:
+            # a device that cannot warm is the breaker's business
+            shapes = 0
+            tel.count("warmup_failures_total")
+            log.warning("AOT re-warm failed: %r", e)
+            self._device_failure(e)
+        seconds = tel.clock() - t0
+        tel.count("rewarms_total")
+        info = self.warmup_info
+        info["rewarms"] = info.get("rewarms", 0) + 1
+        info["rewarm_shapes"] = info.get("rewarm_shapes", 0) + shapes
+        info["rewarm_seconds"] = info.get("rewarm_seconds", 0.0) + seconds
+        if self.gc_guard:
+            # the grown table is the new steady state: keep it out of
+            # full collector passes too, as warmup() did at boot
+            gc.freeze()
+        while self._queue and self._rewarm is None and not self.closed:
+            self._flush()
 
     def _gc_pause(self) -> bool:
         """Suspend the cyclic collector for a launch/collect critical
@@ -597,7 +695,15 @@ class DispatchEngine:
         if self._timer is not None:
             self._timer.cancel()
             self._timer = None
-        batch, self._queue = self._queue, []
+        if self._rewarm_pending():
+            return
+        # one batch is at most queue_depth publishes (the warmed ladder's
+        # top): a queue that grew behind a re-warm flushes in slices
+        if len(self._queue) <= self.queue_depth:
+            batch, self._queue = self._queue, []
+        else:
+            batch = self._queue[: self.queue_depth]
+            del self._queue[: self.queue_depth]
         # collector pauses must not land inside the launch window (the
         # gen-2-pass-in-a-timed-batch outlier PERF_NOTES r5/r6 chased);
         # the pause spans launch + any forced over-depth collects and
@@ -1307,14 +1413,19 @@ class DispatchEngine:
     async def drain(self) -> None:
         """Flush the open batch, admit + serve every blocked waiter,
         and collect everything in flight."""
-        while self._queue or self._inflight or self._waiters:
+        while self._queue or self._inflight or self._waiters or self._rewarm:
+            if self._rewarm is not None:
+                # its landing callback flushes what queued behind it
+                await asyncio.wait({self._rewarm})
+                await asyncio.sleep(0)
+                continue
             if self._waiters:
                 self._pump_waiters()
             if self._queue:
                 self._flush()
             while self._inflight:
                 self._collect_one()
-            if not (self._queue or self._waiters):
+            if not (self._queue or self._waiters or self._rewarm):
                 break
         await asyncio.sleep(0)  # let resolved futures' awaiters run
 
@@ -1329,6 +1440,9 @@ class DispatchEngine:
         if drain:
             await self.drain()
         self.closed = True
+        if self._rewarm is not None:
+            # the worker thread is using the device: let it finish
+            await asyncio.wait({self._rewarm})
         if self._timer is not None:
             self._timer.cancel()
             self._timer = None

@@ -24,7 +24,7 @@ import asyncio
 from typing import Dict, Iterable, List, Optional, Sequence, Set, Tuple
 
 from ..models.retainer import Retainer
-from ..models.router import Router
+from ..models.router import TRIE_REPLAY_STEP, Router
 from ..models.shared_sub import SharedSubs
 from ..obs.profiler import STAGE_MARK
 from ..ops import topic as topic_mod
@@ -92,6 +92,8 @@ class Broker:
         # external tracing seam (emqx_external_trace provider): None
         # costs one attribute check per publish
         self.tracer = None
+        # a host-trie replay step is queued on the loop (_drain_trie)
+        self._trie_drain_scheduled = False
         # fanout plans: matched-filter-set -> (build clock, prebuilt
         # deduped delivery lists) — the ?SUBSCRIBER-bag precomputation,
         # emqx_broker.erl:126-140. Invalidation is PER FILTER: every
@@ -259,6 +261,25 @@ class Broker:
 
     # --- subscribe path --------------------------------------------------
 
+    def _schedule_trie_drain(self) -> None:
+        """Replay a subscribe storm's deferred host-trie inserts a step
+        per event-loop turn, so the first host read after the storm (a
+        $SYS or alarm publish, a sentinel audit) does not stall the
+        loop for all of them at once. Without a running loop the next
+        host read replays them."""
+        try:
+            loop = asyncio.get_running_loop()
+        except RuntimeError:
+            return
+        self._trie_drain_scheduled = True
+        loop.call_soon(self._drain_trie)
+
+    def _drain_trie(self) -> None:
+        if self.router.drain_trie_step(TRIE_REPLAY_STEP):
+            asyncio.get_running_loop().call_soon(self._drain_trie)
+        else:
+            self._trie_drain_scheduled = False
+
     def subscribe(
         self,
         session: Session,
@@ -316,6 +337,11 @@ class Broker:
         else:
             if not existed:
                 self.router.add_route(real, session.client_id)
+                if (
+                    not self._trie_drain_scheduled
+                    and self.router.trie_backlog() > TRIE_REPLAY_STEP
+                ):
+                    self._schedule_trie_drain()
             # stamp the CSR edge with the live suboption (covers
             # resubscribe-with-new-QoS, which has no route transition)
             self.router.fanout_note_opts(real, session.client_id, opts, session)
@@ -461,8 +487,8 @@ class Broker:
         inbound publish batch. A device fault mid-batch fails over to
         the host walk (oracle-identical) instead of failing every
         coalesced publisher — the same failure-domain contract as the
-        pipelined engine, for the synchronous surface (server
-        PublishBatcher, cluster forward legs, bench)."""
+        pipelined engine, for the synchronous surface (cluster
+        forward legs, bench)."""
         rb = getattr(self, "rule_batcher", None)
         if rb is not None and rb.batch_where_enabled:
             # batched-WHERE window: rule predicates hit in the publish

@@ -1092,7 +1092,12 @@ class QuicStreamTransport:
                             )
                             self._ds_abort("publish quota exceeded")
                             return
-                    for reply in ch.handle_packet(pkt):
+                    replies = ch.handle_packet(pkt)
+                    if ch.pending_publish is not None:
+                        # per-stream order: this stream's next packet
+                        # waits for its publish, as on the control stream
+                        replies = replies + await ch.finish_publish()
+                    for reply in replies:
                         out += frame.serialize(reply, ch.proto_ver)
                 if out:
                     self.conn.send_stream(out, sid=sid)

@@ -3,59 +3,28 @@
 One Connection task per client socket (the reference runs one Erlang
 process per connection, emqx_connection.erl:315); inbound bytes flow
 through the incremental Parser into the Channel; deliveries from other
-sessions arrive via the session's outgoing sink. An optional publish
-micro-batcher aggregates concurrent publishes into one TPU match
-dispatch (the batching window the survey calls out, SURVEY.md §7).
+sessions arrive via the session's outgoing sink. When the broker runs
+a dispatch engine, every PUBLISH goes through it, so concurrent
+publishes from all connections coalesce into one device match batch
+(the batching window the survey calls out, SURVEY.md §7).
 """
 
 from __future__ import annotations
 
 import asyncio
 import logging
-from typing import Dict, List, Optional
+from collections import deque
+from typing import Dict, Optional
 
 from .. import framec
 from . import frame
 from .channel import Channel, ProtocolError
 from .limiter import ListenerLimits, LoadShedder
-from .message import Message
 from .packet import Disconnect, MQTT_V5, Publish, RC, Subscribe
 from .pubsub import Broker
 from .transport import TcpTransport, WsTransport
 
 log = logging.getLogger("emqx_tpu.server")
-
-
-class PublishBatcher:
-    """Aggregate publishes across connections into one router batch
-    (mirrors emqx_router_syncer's batching, applied to the read path).
-    Flushes when `max_batch` is reached or `max_delay` elapses."""
-
-    def __init__(self, broker: Broker, max_batch: int = 256, max_delay: float = 0.002):
-        self.broker = broker
-        self.max_batch = max_batch
-        self.max_delay = max_delay
-        self._pending: List[Message] = []
-        self._flusher: Optional[asyncio.TimerHandle] = None
-        self._loop = None
-
-    def submit(self, msg: Message) -> None:
-        self._pending.append(msg)
-        if len(self._pending) >= self.max_batch:
-            self.flush()
-        elif self._flusher is None:
-            if self._loop is None:
-                self._loop = asyncio.get_event_loop()
-            self._flusher = self._loop.call_later(self.max_delay, self.flush)
-
-    def flush(self) -> None:
-        if self._flusher is not None:
-            self._flusher.cancel()
-            self._flusher = None
-        if not self._pending:
-            return
-        batch, self._pending = self._pending, []
-        self.broker.publish_batch(batch)
 
 
 class Connection:
@@ -78,6 +47,9 @@ class Connection:
         # node tier; the ?LIMITER_ROUTING check of emqx_channel.erl:751)
         self.pub_limiter = server.limits.publish_limiter()
         self.byte_limiter = server.limits.bytes_limiter()
+        # (future, ack) of QoS1/2 publishes in the dispatch engine, in
+        # PUBLISH order: answered by _send_acks as the head resolves
+        self._acks: deque = deque()
 
     def _wire_sink(self) -> None:
         sess = self.channel.session
@@ -156,6 +128,24 @@ class Connection:
             self.transport.write(b"".join(chunks))
         except Exception:  # connection already gone; session keeps state
             pass
+
+    def _send_acks(self, _fut=None) -> None:
+        """Answer the resolved publishes at the head of `_acks`. An ack
+        waits for every earlier publish's (MQTT keeps PUBACKs in PUBLISH
+        order), but the parser goes on reading meanwhile, so one
+        connection can have many publishes in a device batch."""
+        acks = self._acks
+        while acks and acks[0][0].done():
+            fut, ack = acks.popleft()
+            try:
+                pkts = self.channel.publish_ack(fut, ack)
+            except (Exception, asyncio.CancelledError):
+                log.exception("engine publish failed; closing connection")
+                acks.clear()
+                self.transport.close()
+                return
+            if pkts:
+                self._send_packets(pkts)
 
     async def run(self) -> None:
         try:
@@ -329,6 +319,11 @@ class Connection:
                         raise
                     if out:
                         self._send_packets(out)
+                    if self.channel.pending_publish is not None:
+                        p = self.channel.take_publish()
+                        if p is not None:
+                            self._acks.append(p)
+                            p[0].add_done_callback(self._send_acks)
                     self._wire_sink()
                 await self.drain()
         except (ProtocolError, ConnectionError):

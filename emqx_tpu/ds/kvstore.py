@@ -35,6 +35,7 @@ from __future__ import annotations
 import ctypes
 import os
 import struct
+import subprocess
 import threading
 import zlib
 from typing import Dict, Iterator, Optional, Tuple
@@ -42,10 +43,10 @@ from typing import Dict, Iterator, Optional, Tuple
 from . import diskio
 from .metrics import DS_METRICS
 
-_LIB_PATHS = [
-    os.path.join(os.path.dirname(__file__), "..", "..", "native", "libemqxkv.so"),
-    os.path.join(os.path.dirname(__file__), "libemqxkv.so"),
-]
+_NATIVE_DIR = os.path.abspath(
+    os.path.join(os.path.dirname(__file__), "..", "..", "native")
+)
+_LIB_PATH = os.path.join(_NATIVE_DIR, "libemqxkv.so")
 
 _TOMBSTONE = 0xFFFFFFFF
 
@@ -54,59 +55,66 @@ WAL_MAGIC = b"EKVWAL2\n"
 
 
 def _load_lib() -> Optional[ctypes.CDLL]:
-    for p in _LIB_PATHS:
-        p = os.path.abspath(p)
-        if os.path.exists(p):
-            try:
-                lib = ctypes.CDLL(p)
-            except OSError:
-                continue
-            lib.kv_open.restype = ctypes.c_void_p
-            lib.kv_open.argtypes = [ctypes.c_char_p]
-            lib.kv_put.restype = ctypes.c_int
-            lib.kv_put.argtypes = [
-                ctypes.c_void_p, ctypes.c_char_p, ctypes.c_uint32,
-                ctypes.c_char_p, ctypes.c_uint32,
-            ]
-            lib.kv_delete.restype = ctypes.c_int
-            lib.kv_delete.argtypes = [ctypes.c_void_p, ctypes.c_char_p, ctypes.c_uint32]
-            lib.kv_get.restype = ctypes.c_int64
-            lib.kv_get.argtypes = [
-                ctypes.c_void_p, ctypes.c_char_p, ctypes.c_uint32,
-                ctypes.POINTER(ctypes.c_char_p),
-            ]
-            lib.kv_count.restype = ctypes.c_uint64
-            lib.kv_count.argtypes = [ctypes.c_void_p]
-            lib.kv_scan.restype = ctypes.c_void_p
-            lib.kv_scan.argtypes = [
-                ctypes.c_void_p, ctypes.c_char_p, ctypes.c_uint32,
-                ctypes.c_char_p, ctypes.c_uint32, ctypes.c_uint64,
-            ]
-            lib.kv_iter_next.restype = ctypes.c_int
-            lib.kv_iter_next.argtypes = [
-                ctypes.c_void_p,
-                ctypes.POINTER(ctypes.c_char_p), ctypes.POINTER(ctypes.c_uint64),
-                ctypes.POINTER(ctypes.c_char_p), ctypes.POINTER(ctypes.c_uint64),
-            ]
-            lib.kv_iter_free.argtypes = [ctypes.c_void_p]
-            lib.kv_flush.restype = ctypes.c_int
-            lib.kv_flush.argtypes = [ctypes.c_void_p]
-            lib.kv_compact.restype = ctypes.c_int
-            lib.kv_compact.argtypes = [ctypes.c_void_p]
-            lib.kv_wal_records.restype = ctypes.c_uint64
-            lib.kv_wal_records.argtypes = [ctypes.c_void_p]
-            lib.kv_torn_records.restype = ctypes.c_uint64
-            lib.kv_torn_records.argtypes = [ctypes.c_void_p]
-            lib.kv_crc_failures.restype = ctypes.c_uint64
-            lib.kv_crc_failures.argtypes = [ctypes.c_void_p]
-            lib.kv_upgraded.restype = ctypes.c_uint64
-            lib.kv_upgraded.argtypes = [ctypes.c_void_p]
-            lib.kv_reopen.restype = ctypes.c_int
-            lib.kv_reopen.argtypes = [ctypes.c_void_p]
-            lib.kv_close.argtypes = [ctypes.c_void_p]
-            lib.kv_kill.argtypes = [ctypes.c_void_p]
-            return lib
-    return None
+    # built from native/kvlog.cc like the other native legs; without a
+    # toolchain the pure-Python PyKv twin serves
+    try:
+        subprocess.run(
+            ["make", "-C", _NATIVE_DIR, "libemqxkv.so"],
+            check=True, capture_output=True, timeout=120,
+        )
+    except Exception:
+        pass
+    if not os.path.exists(_LIB_PATH):
+        return None
+    try:
+        lib = ctypes.CDLL(_LIB_PATH)
+    except OSError:
+        return None
+    lib.kv_open.restype = ctypes.c_void_p
+    lib.kv_open.argtypes = [ctypes.c_char_p]
+    lib.kv_put.restype = ctypes.c_int
+    lib.kv_put.argtypes = [
+        ctypes.c_void_p, ctypes.c_char_p, ctypes.c_uint32,
+        ctypes.c_char_p, ctypes.c_uint32,
+    ]
+    lib.kv_delete.restype = ctypes.c_int
+    lib.kv_delete.argtypes = [ctypes.c_void_p, ctypes.c_char_p, ctypes.c_uint32]
+    lib.kv_get.restype = ctypes.c_int64
+    lib.kv_get.argtypes = [
+        ctypes.c_void_p, ctypes.c_char_p, ctypes.c_uint32,
+        ctypes.POINTER(ctypes.c_char_p),
+    ]
+    lib.kv_count.restype = ctypes.c_uint64
+    lib.kv_count.argtypes = [ctypes.c_void_p]
+    lib.kv_scan.restype = ctypes.c_void_p
+    lib.kv_scan.argtypes = [
+        ctypes.c_void_p, ctypes.c_char_p, ctypes.c_uint32,
+        ctypes.c_char_p, ctypes.c_uint32, ctypes.c_uint64,
+    ]
+    lib.kv_iter_next.restype = ctypes.c_int
+    lib.kv_iter_next.argtypes = [
+        ctypes.c_void_p,
+        ctypes.POINTER(ctypes.c_char_p), ctypes.POINTER(ctypes.c_uint64),
+        ctypes.POINTER(ctypes.c_char_p), ctypes.POINTER(ctypes.c_uint64),
+    ]
+    lib.kv_iter_free.argtypes = [ctypes.c_void_p]
+    lib.kv_flush.restype = ctypes.c_int
+    lib.kv_flush.argtypes = [ctypes.c_void_p]
+    lib.kv_compact.restype = ctypes.c_int
+    lib.kv_compact.argtypes = [ctypes.c_void_p]
+    lib.kv_wal_records.restype = ctypes.c_uint64
+    lib.kv_wal_records.argtypes = [ctypes.c_void_p]
+    lib.kv_torn_records.restype = ctypes.c_uint64
+    lib.kv_torn_records.argtypes = [ctypes.c_void_p]
+    lib.kv_crc_failures.restype = ctypes.c_uint64
+    lib.kv_crc_failures.argtypes = [ctypes.c_void_p]
+    lib.kv_upgraded.restype = ctypes.c_uint64
+    lib.kv_upgraded.argtypes = [ctypes.c_void_p]
+    lib.kv_reopen.restype = ctypes.c_int
+    lib.kv_reopen.argtypes = [ctypes.c_void_p]
+    lib.kv_close.argtypes = [ctypes.c_void_p]
+    lib.kv_kill.argtypes = [ctypes.c_void_p]
+    return lib
 
 
 _LIB = _load_lib()

@@ -211,7 +211,9 @@ def default_rules(
         tel = ctl.telemetry
         if tel is None:
             return None
-        cur = tel.counters.get("recompiles_total", 0)
+        # a warmup pass compiles a whole shape ladder on purpose
+        c = tel.counters
+        cur = c.get("recompiles_total", 0) - c.get("recompiles_warmup_total", 0)
         last, recompile_state["last"] = recompile_state["last"], cur
         if last is not None and cur - last >= recompile_delta:
             return {"recompiles_delta": cur - last, "total": cur}

@@ -5,9 +5,8 @@ filters, but until now that number only existed inside offline
 bench.py runs; the obs/ layer mirrored the reference's broker-level
 surfaces (emqx_prometheus, emqx_opentelemetry) and was blind to the
 device hot path this reproduction exists for. PERF_NOTES.md records
-two full rounds lost to exactly that blindness: the r3→r4
-"regression" that bisected to relay RTT jitter, and p25 estimates
-silently sitting on the epsilon clamp.
+rounds lost to exactly that blindness, such as p25 estimates silently
+sitting on the epsilon clamp.
 
 This module is the always-on collector the Router/DeviceTable hot
 path reports into:
@@ -47,7 +46,9 @@ bound, equals the bench epsilon clamp ceiling by construction.
 
 from __future__ import annotations
 
+import contextlib
 import logging
+import threading
 from bisect import bisect_left
 from time import perf_counter
 from typing import Any, Dict, List, Optional, Sequence, Set, Tuple
@@ -90,8 +91,9 @@ LEG_SYNC = "sync"  # DeviceTable delta scatter / full upload
 # wait + host filter of the device-side cross-shard reduction as
 # `emqx_xla_mesh_combine_seconds` (observe_family), the last fused
 # churn dispatch's row+slot batch as the `mesh_sync_batch_rows` gauge,
-# admission-knob flips to single-device serving as the
-# `mesh_degraded_single_device_total` counter (+ a 0/1 gauge), and
+# admission-knob flips from mesh to single-device serving as the
+# `mesh_degraded_single_device_total` counter (a table that starts
+# below the floor is not a flip; the 0/1 gauge shows the mode), and
 # per-shard host->device upload skew as the labeled counter family
 # `mesh_shard_transfer_rows_total{shard=...}`.
 
@@ -265,6 +267,10 @@ class KernelTelemetry:
         # outlier class the e2e p99 gate bans (counted as
         # `recompiles_at_serve_total`, gated at 0 over the bench run)
         self.serving = False
+        # per-thread warmup window (warming()): the engine re-warms a
+        # grown table's shapes on a worker thread while the loop keeps
+        # serving, so the flag cannot be the process-wide `serving`
+        self._warm_local = threading.local()
 
     # --- dispatch histograms ---------------------------------------------
 
@@ -356,7 +362,9 @@ class KernelTelemetry:
             return False
         seen.add(key)
         self.count("recompiles_total")
-        if self.serving:
+        if getattr(self._warm_local, "on", False):
+            self.count("recompiles_warmup_total")
+        elif self.serving:
             self.count("recompiles_at_serve_total")
         fr = self.flight
         if fr is not None:
@@ -375,6 +383,16 @@ class KernelTelemetry:
 
     def shape_buckets(self) -> Dict[str, int]:
         return {k: len(v) for k, v in self._shape_keys.items()}
+
+    @contextlib.contextmanager
+    def warming(self):
+        """Shapes this thread records inside the block are warmup, not
+        serve-time compiles, whether or not the collector is serving."""
+        self._warm_local.on = True
+        try:
+            yield
+        finally:
+            self._warm_local.on = False
 
     def mark_serving(self) -> None:
         """Close the AOT-warmup window: every shape bucket traced from
@@ -557,6 +575,9 @@ class NullKernelTelemetry:
 
     def record_shape(self, kernel, key) -> bool:
         return False
+
+    def warming(self):
+        return contextlib.nullcontext()
 
     def shape_buckets(self) -> Dict[str, int]:
         return {}

@@ -465,7 +465,10 @@ class PublishSentinel:
         """Record one served publish for deferred re-verification. The
         hot path cost is one deque append; the oracle walk runs on a
         later event-loop turn (or inline when no loop is running —
-        bench/offline use)."""
+        bench/offline use). A full queue drops its oldest capture,
+        counted as `audit_dropped_total`."""
+        if len(self._pending) == self._pending.maxlen:
+            self.telemetry.count("audit_dropped_total")
         self._pending.append(
             _AuditRecord(topic, filters, pairs, gen, trace_id)
         )

@@ -79,6 +79,7 @@ from .table import FilterTable
 from .vocab import PLUS
 
 DEFAULT_CLASS_BUDGET = 256
+MIN_PACKED_CLASSES = 8  # floor of the uploaded class prefix (packed_len)
 BUCKET_W = 4  # slots per bucket: one u32 probe word per bucket
 MAX_KICKS = 512  # eviction-walk bound before a rebuild
 MIN_SLOTS = 1024
@@ -428,10 +429,16 @@ class ClassIndex:
         act = np.flatnonzero(self.meta.active)
         return int(act[-1]) + 1 if len(act) else 0
 
-    def packed_meta(self) -> "ClassMeta":
-        """Meta arrays sliced to a pow2 >= active_hi (>=1)."""
+    def packed_len(self) -> int:
+        """Class rows packed_meta uploads: a pow2 >= active_hi, at
+        least MIN_PACKED_CLASSES, so the first few classes a broker
+        sees do not each change the kernel shape."""
         hi = 1 << max(0, self.active_hi() - 1).bit_length()
-        hi = max(1, min(hi, self.class_budget))
+        return max(1, min(max(hi, MIN_PACKED_CLASSES), self.class_budget))
+
+    def packed_meta(self) -> "ClassMeta":
+        """Meta arrays sliced to packed_len()."""
+        hi = self.packed_len()
         return ClassMeta(*(np.ascontiguousarray(a[:hi]) for a in self.meta))
 
     # --- write path ----------------------------------------------------

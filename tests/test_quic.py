@@ -482,13 +482,18 @@ def test_newreno_congestion_control():
     assert srv.streams[0].rx_off >= 200_000, "backlog never drained"
 
 
-async def test_multistream_mqtt_data_streams():
+@pytest.mark.parametrize("engine", [False, True], ids=["host", "engine"])
+async def test_multistream_mqtt_data_streams(engine):
     """Multi-stream mode (emqx_quic_data_stream.erl): CONNECT on the
     control stream, PUBLISH on a data stream — the PUBACK returns on
     the SAME data stream, the delivery rides the control stream, and
     a second data stream works independently. Connection-level packets
-    on a data stream kill the connection."""
+    on a data stream kill the connection. With the dispatch engine on,
+    the data stream's QoS1 publish is matched by the device kernels and
+    acked on its stream once the engine resolves it."""
     broker = Broker()
+    if engine:
+        broker.enable_dispatch_engine()
     mqtt_seat = Server(broker, host="127.0.0.1", port=0, name="quic:ms")
     quic = QuicServer(mqtt_seat, host="127.0.0.1", port=0)
     await quic.start()
@@ -539,6 +544,10 @@ async def test_multistream_mqtt_data_streams():
         assert type(ds2[0]).__name__ == "Puback" and ds2[0].packet_id == 11
         pub2 = await read_ctrl()
         assert pub2.payload == b"ds2"
+        batches = broker.router.telemetry.counters.get(
+            "dispatch_batches_total", 0
+        )
+        assert (batches > 0) == engine
 
         # CONNECT on a data stream is a protocol violation
         s3 = ep.open_stream()
@@ -551,3 +560,5 @@ async def test_multistream_mqtt_data_streams():
     finally:
         ep.close()
         await quic.stop()
+        if broker.engine is not None:
+            await broker.engine.stop()

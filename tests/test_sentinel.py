@@ -408,3 +408,15 @@ def test_quarantine_refuses_device_fanout(tmp_path):
         assert len(mem) == 8
     finally:
         obs.stop()
+
+
+async def test_full_audit_queue_counts_its_drops():
+    from emqx_tpu.obs.sentinel import PublishSentinel
+
+    b = Broker()
+    st = PublishSentinel(b, sample_n=1, max_pending_audits=2)
+    for _ in range(5):
+        st.capture_audit("t/1", (), [], b.router.generation)
+    assert b.router.telemetry.counters["audit_dropped_total"] == 3
+    await asyncio.sleep(0)
+    assert b.router.telemetry.counters["audit_total"] == 2
