@@ -3316,7 +3316,7 @@ def bench_profile(details, out_path="PROFILE_r19.json"):
     and the 100Hz sampling profiler armed, then commit PROFILE_r17:
     the queue-stage p99 attributed to the six named sub-stages (whose
     sums must land within 10% of the queue+deliver wall), the top-10
-    stacks per sub-stage, ring occupancy + loop lag over the storm,
+    stacks per sub-stage, ring slot timeline + loop lag over the storm,
     the paired-toggle profiler overhead figure, and the two zeros the
     round is gated on — recompiles_at_serve_total and silent
     divergences on the accompanying audit sweep.
@@ -3465,7 +3465,7 @@ def bench_profile(details, out_path="PROFILE_r19.json"):
         f"{len(delivery)} sub-stages sum/wall {ratio:.3f}, "
         f"profiler {pstat['samples_total']} samples "
         f"({pstat['unique_stacks']} stacks), "
-        f"ring occupancy {ring.get('occupancy_ratio')}, "
+        f"ring slots {ring.get('slots_total')}, "
         f"silent {audit['silent_divergences']} -> {out_path}"
     )
     return row

@@ -607,6 +607,10 @@ class Node:
             license=self.license,
             obs=self.obs,
         )
+        # the stage register's spans and its `gc` stage follow this loop
+        from .obs.profiler import STAGE_MARK
+
+        STAGE_MARK.attach()
         log.info("node %s started", node_name)
 
     async def stop(self) -> None:
@@ -654,6 +658,9 @@ class Node:
         retainer = getattr(self.broker, "retainer", None)
         if retainer is not None and hasattr(retainer, "close"):
             retainer.close()
+        from .obs.profiler import STAGE_MARK
+
+        STAGE_MARK.detach()
         log.info("node stopped")
 
     async def run_forever(self) -> None:

@@ -409,15 +409,11 @@ class Channel:
 
     def _publish_engine(self):
         """The dispatch engine a PUBLISH goes through, or None for the
-        synchronous host publish: no engine, an engine that stopped, or
-        an external tracer, whose spans wrap only that path. A traced
-        publish that could have used the engine is counted, so a node
-        with tracing on shows how much it served from the host trie."""
+        synchronous host publish: no engine, or an engine that stopped.
+        An external tracer rides the engine (it spans each publish of a
+        batch)."""
         eng = self.broker.engine
         if eng is None or eng.closed:
-            return None
-        if self.broker.tracer is not None:
-            eng.telemetry.count("traced_host_publish_total")
             return None
         return eng
 

@@ -102,6 +102,7 @@ from collections import deque
 from contextlib import nullcontext
 from typing import Deque, Dict, List, Optional, Set, Tuple
 
+from ..obs.otel import publish_root
 from ..obs.profiler import STAGE_MARK
 from .message import Message
 
@@ -272,14 +273,8 @@ class DispatchEngine:
         # canary topics: the most recent distinct batch heads, so the
         # recovery probe dispatches realistic traffic, not synthetics
         self._recent_topics: Deque[str] = deque(maxlen=8)
-        # --- device-occupancy timeline (ISSUE 17): launch->land spans
-        # per ring slot, busy-time integral over empty->nonempty
-        # transitions of _inflight, and the idle gaps between lands —
-        # "the device is idle 97% of the time" as a committed number
-        self._ring_track_since: Optional[float] = None
-        self._ring_busy_since: Optional[float] = None
-        self._ring_last_land: Optional[float] = None
-        self._ring_busy_accum = 0.0
+        # --- ring slot timeline: launch->land spans per ring slot, on
+        # the host clock (device idle time is the device trace's)
         self._ring_slots_total = 0
         self._ring_timeline: Deque[Dict] = deque(maxlen=64)
         tel = self.telemetry
@@ -728,7 +723,17 @@ class DispatchEngine:
                 if rb is not None and rb.batch_where_enabled
                 else nullcontext()
             )
-            STAGE_MARK.stage = "coalesce"
+            mark = STAGE_MARK
+            prev_stage = mark.enter("coalesce")
+            if mark.span is not None:
+                mark.span.set_metadata(publishes=len(batch))
+            # external tracer (obs/otel.py): each publish's mqtt.publish
+            # root opens here; one attribute read per untraced batch
+            tr = broker.tracer
+            roots = (
+                [publish_root(tr, msg) for msg, _f, _t, _s in batch]
+                if tr is not None else None
+            )
             with win:
                 for msg, fut, t_in, span in batch:
                     tel.observe_family(
@@ -751,14 +756,17 @@ class DispatchEngine:
                     entries.append((live, fut, span))
                     if live is not None:
                         topics.append(live.topic)
-            STAGE_MARK.stage = ""
             self.batches_total += 1
             self.publishes_total += len(batch)
             if topics:
                 self._recent_topics.append(topics[0])
-            # match_launch mark: topic encode + kernel dispatch — the
-            # submit-path cost the profiler used to file under `other`
-            STAGE_MARK.stage = "match_launch"
+            # match_launch: cache probe and table sync; the router marks
+            # its encode, launch and ticket_start inside it
+            mark.enter("match_launch")
+            otel = (
+                self._otel_route(tr, roots, entries)
+                if roots is not None else None
+            )
             try:
                 pending = router.match_filters_begin(topics, span=bspan)
             except Exception as e:
@@ -774,7 +782,7 @@ class DispatchEngine:
             # materializes on device while the match hash fetch for the
             # uncached remainder is still in flight
             fanout_pending = None
-            STAGE_MARK.stage = "plan_resolve"
+            mark.enter("plan_resolve")
             if (
                 broker._fanout_device
                 and pending.full_out is not None
@@ -806,20 +814,9 @@ class DispatchEngine:
                         fanout_pending.append(
                             (fkey, broker._fanout_clock, h)
                         )
-            STAGE_MARK.stage = ""
-            t_launch = tel.clock()
-            if self._ring_track_since is None:
-                self._ring_track_since = t_launch
-            if self._ring_busy_since is None:
-                # empty->nonempty transition: the gap since the last
-                # land is device idle time — the timeline's blank space
-                self._ring_busy_since = t_launch
-                if self._ring_last_land is not None:
-                    tel.observe_family(
-                        "ring_gap_seconds", t_launch - self._ring_last_land
-                    )
+            mark.leave(prev_stage)
             self._inflight.append(
-                (pending, entries, fanout_pending, bspan, t_launch)
+                (pending, entries, fanout_pending, bspan, tel.clock(), otel)
             )
             self._inflight_pubs += len(entries)
             tel.set_gauge("pipeline_depth", len(self._inflight))
@@ -852,7 +849,7 @@ class DispatchEngine:
         """True when collecting the ring head will not block: the
         match legs' AND any overlapped fanout resolves' transfer
         tickets have all landed host-side."""
-        pending, _entries, fanout_pending, _bspan, _t = self._inflight[0]
+        pending, _entries, fanout_pending, _bspan, _t, _otel = self._inflight[0]
         if not self.router.match_finish_ready(pending):
             return False
         if fanout_pending is not None:
@@ -891,7 +888,7 @@ class DispatchEngine:
         walk; a slow-but-successful device batch past the breaker
         deadline counts toward the breaker without being re-served
         (its results are already correct)."""
-        pending, entries, fanout_pending, bspan, t_launch = (
+        pending, entries, fanout_pending, bspan, t_launch, otel = (
             self._inflight.popleft()
         )
         broker = self.broker
@@ -901,11 +898,10 @@ class DispatchEngine:
         tclock = tel.clock
         device_batch = pending.mode not in ("cached", "host")
         gc_tok = self._gc_pause()
+        # match_fetch: device->host transfer + unpack of the match result
+        mark = STAGE_MARK
+        prev_stage = mark.enter("match_fetch")
         try:
-            # match_fetch mark: device->host transfer + unpack of the
-            # match result — the drain-path cost the profiler used to
-            # file under `other`
-            STAGE_MARK.stage = "match_fetch"
             t0 = tclock()
             try:
                 filter_lists = router.match_filters_finish(pending)
@@ -920,11 +916,12 @@ class DispatchEngine:
                 try:
                     filter_lists = router.match_filters_host(pending)
                 except Exception as e2:  # host truth failed: nothing left
-                    STAGE_MARK.stage = ""
                     tel.count("publish_failures_total", len(entries))
                     for _live, fut, _span in entries:
                         if not fut.done():
                             fut.set_exception(e2)
+                    if otel is not None:
+                        self._otel_finish(otel, entries, [e2] * len(entries))
                     self._ring_land(tclock(), t_launch, "failed", len(entries))
                     self._batch_done(len(entries))
                     return
@@ -940,13 +937,14 @@ class DispatchEngine:
                         self._device_failure(None)
                     else:
                         self._device_success()
-            STAGE_MARK.stage = ""
+            if otel is not None:
+                self._otel_dispatch(otel, entries, filter_lists)
             if fanout_pending is not None:
                 # install the overlapped plans before delivering: stamped
                 # with the clock captured at begin, so a mutation that
                 # landed mid-flight leaves them stale-on-arrival and the
                 # dispatch below rebuilds — exactness over hit ratio
-                STAGE_MARK.stage = "plan_resolve"
+                mark.enter("plan_resolve")
                 t_res = tclock() if bspan is not None else 0.0
                 for fkey, clock, h in fanout_pending:
                     try:
@@ -960,12 +958,12 @@ class DispatchEngine:
                     broker._store_plan(fkey, clock, plan)
                 if bspan is not None:
                     bspan.add("resolve", tclock() - t_res)
-                STAGE_MARK.stage = ""
             self._ring_land(tclock(), t_launch, pending.mode, len(entries))
             # the vectorized delivery half: ONE window dispatch for the
             # whole collected batch (plan resolution per unique filter
             # set, session-grouped writes) instead of a per-publish
             # _dispatch loop — see Broker.dispatch_window
+            mark.enter("dispatch_loop")
             results, meta = broker.dispatch_window(
                 [e[0] for e in entries],
                 filter_lists,
@@ -1024,8 +1022,11 @@ class DispatchEngine:
                     pend_total = n
                     pend_k = 1
             _flush_agg()
+            if otel is not None:
+                self._otel_finish(otel, entries, results)
             self._batch_done(len(entries))
         finally:
+            mark.leave(prev_stage)
             self._gc_resume(gc_tok)
 
     def _batch_done(self, n_pubs: int) -> None:
@@ -1035,18 +1036,63 @@ class DispatchEngine:
         else:
             self._maybe_clear_overload()
 
-    # --- device-occupancy timeline ---------------------------------------
+    # --- external tracer (obs/otel.py) ------------------------------------
+    # A batch with broker.tracer set gives each publish the spans the
+    # synchronous host publish gives it: an mqtt.publish root opened at
+    # the flush, broker.route from the match begin to its finish, and
+    # broker.dispatch over the window dispatch. `otel` is (tracer,
+    # [[root, child span or None]] in entry order).
+
+    @staticmethod
+    def _otel_route(tr, roots, entries) -> tuple:
+        pairs = []
+        for root, (live, _fut, _span) in zip(roots, entries):
+            if live is None:  # a publish hook dropped it
+                root.set("mqtt.dropped", True)
+                pairs.append([root, None])
+            else:
+                pairs.append(
+                    [root, tr.start_span("broker.route", root.trace_id, root)]
+                )
+        return tr, pairs
+
+    @staticmethod
+    def _otel_dispatch(otel, entries, filter_lists) -> None:
+        tr, pairs = otel
+        flts = iter(filter_lists)
+        for pair, (live, _fut, _span) in zip(pairs, entries):
+            root, rs = pair
+            if rs is None:
+                continue
+            rs.set("broker.matched_filters", len(next(flts)))
+            tr.finish(rs)
+            pair[1] = tr.start_span("broker.dispatch", root.trace_id, root)
+            live.headers["trace_root"] = root  # cluster leg parents here
+
+    @staticmethod
+    def _otel_finish(otel, entries, results) -> None:
+        tr, pairs = otel
+        for (root, sp), (live, _fut, _span), n in zip(pairs, entries, results):
+            if live is not None:
+                live.headers.pop("trace_root", None)
+            if isinstance(n, BaseException):
+                root.set("error", repr(n))
+            elif sp is not None:
+                sp.set("broker.deliveries", n)
+                root.set("mqtt.deliveries", n)
+            if sp is not None:
+                tr.finish(sp)
+            tr.finish(root)
+
+    # --- ring slot timeline ------------------------------------------------
 
     def _ring_land(
         self, t_land: float, t_launch: float, mode: str, n_pubs: int
     ) -> None:
-        """One ring slot landed: record its launch->land span, stamp
-        the timeline, and close the busy segment when the ring just
-        went empty (the occupancy integral only advances on
-        transitions — zero cost while the ring stays busy)."""
+        """One ring slot landed: record its launch->land span (host
+        clock) and stamp the timeline."""
         tel = self.telemetry
         self._ring_slots_total += 1
-        self._ring_last_land = t_land
         tel.observe_family("ring_slot_span_seconds", t_land - t_launch)
         self._ring_timeline.append(
             {
@@ -1057,29 +1103,10 @@ class DispatchEngine:
                 "publishes": n_pubs,
             }
         )
-        if not self._inflight and self._ring_busy_since is not None:
-            self._ring_busy_accum += t_land - self._ring_busy_since
-            self._ring_busy_since = None
-            tel.set_gauge("ring_occupancy_ratio", self._ring_occupancy())
-
-    def _ring_occupancy(self) -> float:
-        """Busy-time fraction since tracking began: the committed
-        answer to 'how idle is the device, really'."""
-        since = self._ring_track_since
-        if since is None:
-            return 0.0
-        now = self.telemetry.clock()
-        busy = self._ring_busy_accum
-        if self._ring_busy_since is not None:
-            busy += now - self._ring_busy_since
-        elapsed = now - since
-        return min(1.0, busy / elapsed) if elapsed > 0 else 0.0
 
     def ring_status(self) -> Dict:
         out = {
             "slots_total": self._ring_slots_total,
-            "occupancy_ratio": round(self._ring_occupancy(), 6),
-            "busy_seconds": round(self._ring_busy_accum, 6),
             "timeline": list(self._ring_timeline),
         }
         # mesh microscope: per-chip generalization of the ring ledger

@@ -33,6 +33,8 @@ import asyncio
 import time
 from typing import Dict, List, Optional, Sequence
 
+from ..obs.profiler import unstaged
+
 INF = float("inf")
 
 
@@ -112,7 +114,7 @@ class Limiter:
                 return True
             if w == INF or waited + w > max_wait:
                 return False
-            await asyncio.sleep(min(w, 1.0))
+            await unstaged(asyncio.sleep(min(w, 1.0)))
             waited += min(w, 1.0)
 
 

@@ -452,14 +452,11 @@ class Broker:
         """The external-trace leg (emqx_external_trace.erl:29-123 /
         emqx_otel_trace spans around route + dispatch); lives off the
         None-tracer hot path entirely."""
-        from ..obs.otel import trace_id_of
+        from ..obs.otel import publish_root
 
         tr = self.tracer
-        tid = trace_id_of(msg)
-        root = tr.start_span("mqtt.publish", tid, None)
-        root.set("mqtt.topic", msg.topic).set("mqtt.qos", msg.qos)
-        if msg.from_client:
-            root.set("mqtt.clientid", msg.from_client)
+        root = publish_root(tr, msg)
+        tid = root.trace_id
         try:
             out = self._pre_publish(msg)
             if out is None:
@@ -900,6 +897,8 @@ class Broker:
         """Build the (mem, other) plan for a matched filter set —
         device kernel when eligible, else the host oracle walk. The
         two are bit-identical by contract (churn-oracle-tested)."""
+        prev_stage = STAGE_MARK.enter("plan_resolve")
+        plan = None
         if self._fanout_device:
             router = self.router
             try:
@@ -911,7 +910,6 @@ class Broker:
                     eng = self.engine
                     if eng is not None:
                         eng.note_device_success()
-                    return plan
             except Exception as e:
                 # device fault on the synchronous resolve leg: the
                 # host walk below is the oracle the kernel is
@@ -923,7 +921,10 @@ class Broker:
                 eng = self.engine
                 if eng is not None:
                     eng.note_device_failure(e)
-        return self._build_fanout_plan(pairs)
+        if plan is None:
+            plan = self._build_fanout_plan(pairs)
+        STAGE_MARK.leave(prev_stage)
+        return plan
 
     def _build_fanout_plan(self, pairs: Pairs) -> tuple:
         """(mem_entries, other_entries): mem = live in-memory sessions
@@ -1056,13 +1057,15 @@ class Broker:
         shards (already credited at plan time)."""
         bcast, rest, other = fast
         mark = STAGE_MARK
-        mark.stage = "dispatch_loop"
+        prev_stage = mark.enter("dispatch_loop")
+        W = len(msgs)
+        if mark.span is not None:
+            mark.span.set_metadata(messages=W, subscribers=hi - lo)
         run_hook = self.hooks.has("message.delivered")
         hooks_run = self.hooks.run_unobserved
-        W = len(msgs)
         nb = len(bcast)
         if lo < nb:
-            mark.stage = "session_write"
+            mark.enter("session_write")
             pkts0 = wctx.get("pkts0")
             if pkts0 is None:
                 pkts0 = []
@@ -1125,7 +1128,7 @@ class Broker:
             if counts is not None and hit:
                 for j in range(W):
                     counts[j] += hit
-            mark.stage = "dispatch_loop"
+            mark.enter("dispatch_loop")
         m_end = nb + len(rest)
         if hi > nb and lo < m_end:
             for client, s, opts in rest[max(lo - nb, 0):min(hi, m_end) - nb]:
@@ -1171,7 +1174,7 @@ class Broker:
                             sink(packets)
                     if counts is not None:
                         counts[j] += 1
-        mark.stage = ""
+        mark.leave(prev_stage)
 
     def _shared_pkt(self, msg: Message, retain: bool, pkt_cache) -> tuple:
         pkt = Publish(
@@ -1205,12 +1208,14 @@ class Broker:
         (frame.serialize memoizes on the shared packet)."""
         bcast, rest, other = fast
         n = 0
-        # profiler stage marks (obs/profiler.STAGE_MARK): one store per
-        # LEG, read by the sampling thread to bucket stacks. The bcast
-        # leg is serialize+socket-write by construction, so it samples
-        # as session_write; the mixed legs sample as dispatch_loop.
+        # profiler stage marks (obs/profiler.STAGE_MARK): one transition
+        # per LEG. The bcast leg is serialize+socket-write by
+        # construction, so it is session_write; the mixed legs are
+        # dispatch_loop.
         mark = STAGE_MARK
-        mark.stage = "dispatch_loop"
+        prev_stage = mark.enter("dispatch_loop")
+        if mark.span is not None:
+            mark.span.set_metadata(subscribers=hi - lo)
         run_hook = self.hooks.has("message.delivered")
         # per-delivery hookpoints are untimed by contract (obs/
         # flight_recorder UNTIMED_HOOKPOINTS): the probe-free runner
@@ -1220,7 +1225,7 @@ class Broker:
         mq = msg.qos
         nb = len(bcast)
         if lo < nb:
-            mark.stage = "session_write"
+            mark.enter("session_write")
             cached = pkt_cache.get(False)
             if cached is None:
                 cached = self._shared_pkt(msg, False, pkt_cache)
@@ -1266,7 +1271,7 @@ class Broker:
                     if sink is not None:
                         sink(packets)
                 n += 1
-            mark.stage = "dispatch_loop"
+            mark.enter("dispatch_loop")
         m = nb + len(rest)
         if hi > nb and lo < m:
             for client, s, opts in rest[max(lo - nb, 0):min(hi, m) - nb]:
@@ -1320,7 +1325,7 @@ class Broker:
                     if sink is not None:
                         sink(packets)
                 n += 1
-        mark.stage = ""
+        mark.leave(prev_stage)
         return n
 
     def _deliver_plan_timed(

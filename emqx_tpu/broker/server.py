@@ -17,6 +17,7 @@ from collections import deque
 from typing import Dict, Optional
 
 from .. import framec
+from ..obs.profiler import STAGE_MARK, unstaged
 from . import frame
 from .channel import Channel, ProtocolError
 from .limiter import ListenerLimits, LoadShedder
@@ -133,8 +134,10 @@ class Connection:
         """Answer the resolved publishes at the head of `_acks`. An ack
         waits for every earlier publish's (MQTT keeps PUBACKs in PUBLISH
         order), but the parser goes on reading meanwhile, so one
-        connection can have many publishes in a device batch."""
+        connection can have many publishes in a device batch. The
+        `ack_write` stage (obs/profiler.STAGE_MARK)."""
         acks = self._acks
+        prev_stage = STAGE_MARK.enter("ack_write")
         while acks and acks[0][0].done():
             fut, ack = acks.popleft()
             try:
@@ -143,9 +146,10 @@ class Connection:
                 log.exception("engine publish failed; closing connection")
                 acks.clear()
                 self.transport.close()
-                return
+                break
             if pkts:
                 self._send_packets(pkts)
+        STAGE_MARK.leave(prev_stage)
 
     async def run(self) -> None:
         try:
@@ -166,165 +170,13 @@ class Connection:
                     break  # keepalive/connect timeout
                 if not data:
                     break
+                prev_stage = STAGE_MARK.enter("decode")
                 try:
-                    pkts = self.parser.feed(data)
-                except frame.FrameError as e:
-                    if self.channel.proto_ver == MQTT_V5 and self.channel.connected:
-                        self._send_packets([Disconnect(e.code)])
+                    more = await self._handle_read(data)
+                finally:
+                    STAGE_MARK.leave(prev_stage)
+                if not more:
                     break
-                for pkt in pkts:
-                    from .packet import Connect
-
-                    if isinstance(pkt, Connect) and not self.channel.connected:
-                        hooks = self.server.broker.hooks
-                        # 'client.connect' gate (license quota, exhook
-                        # OnClientConnect) runs FIRST — a shed CONNECT
-                        # must not cost an auth-backend round trip. Run
-                        # it off-loop when a slow (out-of-proc) hook is
-                        # registered, same posture as authenticate.
-                        cinfo = dict(
-                            client_id=pkt.client_id,
-                            username=pkt.username,
-                            proto_ver=pkt.proto_ver,
-                            keepalive=pkt.keepalive,
-                            clean_start=pkt.clean_start,
-                            peer=self.channel.peer,
-                        )
-                        if hooks.has_slow("client.connect"):
-                            cverdict = await (
-                                asyncio.get_running_loop().run_in_executor(
-                                    None,
-                                    lambda: hooks.run_fold(
-                                        "client.connect", (cinfo,), True
-                                    ),
-                                )
-                            )
-                        elif hooks.has("client.connect"):
-                            cverdict = hooks.run_fold(
-                                "client.connect", (cinfo,), True
-                            )
-                        else:
-                            cverdict = True
-                        self.channel.preconnect = (pkt.client_id, cverdict)
-                        if cverdict is not True:
-                            # shed before the auth fold runs at all
-                            self.channel.preauth = (pkt.client_id, True)
-                        else:
-                            # run the authenticate fold OFF-loop:
-                            # providers doing network IO (HTTP authn)
-                            # block for up to their timeout, and that
-                            # must stall only THIS connection — never
-                            # the whole broker loop
-                            info = dict(
-                                client_id=pkt.client_id,
-                                username=pkt.username,
-                                password=pkt.password,
-                                peer=self.channel.peer,
-                            )
-                            verdict = await (
-                                asyncio.get_running_loop().run_in_executor(
-                                    None,
-                                    lambda: hooks.run_fold(
-                                        "client.authenticate", (info,), True
-                                    ),
-                                )
-                            )
-                            self.channel.preauth = (pkt.client_id, verdict)
-                    if isinstance(pkt, Publish):
-                        # backpressure: pausing here stops reading the
-                        # socket, which pushes back on the publisher's
-                        # TCP window (the reference hibernates the
-                        # connection process the same way)
-                        ok = await self.pub_limiter.acquire(1.0)
-                        ok = ok and await self.byte_limiter.acquire(
-                            float(len(pkt.payload))
-                        )
-                        if not ok:
-                            self.server.broker.metrics.inc(
-                                "messages.dropped.quota_exceeded"
-                            )
-                            if self.channel.proto_ver == MQTT_V5:
-                                self._send_packets(
-                                    [Disconnect(RC.QUOTA_EXCEEDED)]
-                                )
-                            return
-                    if self.channel.connected and isinstance(
-                        pkt, (Publish, Subscribe)
-                    ):
-                        # verdicts are scoped to THIS packet: always
-                        # reset so nothing stale survives a has_slow
-                        # flip or an unconsumed rewrite miss
-                        self.channel.preauthz = {}
-                        self.channel.presub_filters = None
-                    if self.channel.connected and isinstance(
-                        pkt, (Publish, Subscribe)
-                    ) and self.server.broker.hooks.has_slow("client.authorize"):
-                        # a network-backed authz source (or exhook) is
-                        # installed: pre-resolve the verdicts OFF-loop so
-                        # a backend stall pushes back on this connection
-                        # only, never the broker loop (same pattern as
-                        # the authenticate fold above)
-                        cid = self.channel.client_id
-                        hooks = self.server.broker.hooks
-                        if isinstance(pkt, Publish):
-                            t = pkt.topic or self.channel.topic_aliases.get(
-                                pkt.props.get("topic_alias")
-                            )
-                            if t:
-                                self.channel.preauthz = (
-                                    await asyncio.get_running_loop().run_in_executor(
-                                        None,
-                                        lambda: {
-                                            ("publish", t): hooks.run_fold(
-                                                "client.authorize",
-                                                (cid, "publish", t),
-                                                True,
-                                            )
-                                        },
-                                    )
-                                )
-                        else:
-                            # run the client.subscribe fold HERE (once,
-                            # off-loop) so rewritten filters get their
-                            # verdicts pre-resolved too; the channel
-                            # consumes the folded list instead of re-
-                            # running the chain (presub)
-                            def _presub(pkt=pkt):
-                                acc = hooks.run_fold(
-                                    "client.subscribe", (cid,), pkt.filters
-                                )
-                                filters = (
-                                    acc if acc is not None else pkt.filters
-                                )
-                                verdicts = {
-                                    ("subscribe", f): hooks.run_fold(
-                                        "client.authorize",
-                                        (cid, "subscribe", f),
-                                        True,
-                                    )
-                                    for f, _o in filters
-                                }
-                                return filters, verdicts
-                            (
-                                self.channel.presub_filters,
-                                self.channel.preauthz,
-                            ) = await asyncio.get_running_loop().run_in_executor(
-                                None, _presub
-                            )
-                    try:
-                        out = self.channel.handle_packet(pkt)
-                    except ProtocolError as e:
-                        if self.channel.proto_ver == MQTT_V5:
-                            self._send_packets([Disconnect(e.code)])
-                        raise
-                    if out:
-                        self._send_packets(out)
-                    if self.channel.pending_publish is not None:
-                        p = self.channel.take_publish()
-                        if p is not None:
-                            self._acks.append(p)
-                            p[0].add_done_callback(self._send_acks)
-                    self._wire_sink()
                 await self.drain()
         except (ProtocolError, ConnectionError):
             pass
@@ -338,6 +190,179 @@ class Connection:
                 sess.closer = None
             self.channel.on_close()
             self.transport.close()
+
+    async def _handle_read(self, data: bytes) -> bool:
+        """Decode one read and handle its packets, as the `decode` and
+        then the `channel` stage (obs/profiler.STAGE_MARK). False when
+        the connection must close."""
+        mark = STAGE_MARK
+        if mark.span is not None:
+            mark.span.set_metadata(bytes=len(data))
+        try:
+            pkts = self.parser.feed(data)
+        except frame.FrameError as e:
+            if self.channel.proto_ver == MQTT_V5 and self.channel.connected:
+                self._send_packets([Disconnect(e.code)])
+            return False
+        mark.enter("channel")
+        if mark.span is not None:
+            mark.span.set_metadata(packets=len(pkts))
+        for pkt in pkts:
+            from .packet import Connect
+
+            if isinstance(pkt, Connect) and not self.channel.connected:
+                hooks = self.server.broker.hooks
+                # 'client.connect' gate (license quota, exhook
+                # OnClientConnect) runs FIRST — a shed CONNECT
+                # must not cost an auth-backend round trip. Run
+                # it off-loop when a slow (out-of-proc) hook is
+                # registered, same posture as authenticate.
+                cinfo = dict(
+                    client_id=pkt.client_id,
+                    username=pkt.username,
+                    proto_ver=pkt.proto_ver,
+                    keepalive=pkt.keepalive,
+                    clean_start=pkt.clean_start,
+                    peer=self.channel.peer,
+                )
+                if hooks.has_slow("client.connect"):
+                    cverdict = await unstaged(
+                        asyncio.get_running_loop().run_in_executor(
+                            None,
+                            lambda: hooks.run_fold(
+                                "client.connect", (cinfo,), True
+                            ),
+                        )
+                    )
+                elif hooks.has("client.connect"):
+                    cverdict = hooks.run_fold(
+                        "client.connect", (cinfo,), True
+                    )
+                else:
+                    cverdict = True
+                self.channel.preconnect = (pkt.client_id, cverdict)
+                if cverdict is not True:
+                    # shed before the auth fold runs at all
+                    self.channel.preauth = (pkt.client_id, True)
+                else:
+                    # run the authenticate fold OFF-loop:
+                    # providers doing network IO (HTTP authn)
+                    # block for up to their timeout, and that
+                    # must stall only THIS connection — never
+                    # the whole broker loop
+                    info = dict(
+                        client_id=pkt.client_id,
+                        username=pkt.username,
+                        password=pkt.password,
+                        peer=self.channel.peer,
+                    )
+                    verdict = await unstaged(
+                        asyncio.get_running_loop().run_in_executor(
+                            None,
+                            lambda: hooks.run_fold(
+                                "client.authenticate", (info,), True
+                            ),
+                        )
+                    )
+                    self.channel.preauth = (pkt.client_id, verdict)
+            if isinstance(pkt, Publish):
+                # backpressure: pausing here stops reading the
+                # socket, which pushes back on the publisher's
+                # TCP window (the reference hibernates the
+                # connection process the same way)
+                ok = await self.pub_limiter.acquire(1.0)
+                ok = ok and await self.byte_limiter.acquire(
+                    float(len(pkt.payload))
+                )
+                if not ok:
+                    self.server.broker.metrics.inc(
+                        "messages.dropped.quota_exceeded"
+                    )
+                    if self.channel.proto_ver == MQTT_V5:
+                        self._send_packets(
+                            [Disconnect(RC.QUOTA_EXCEEDED)]
+                        )
+                    return False
+            if self.channel.connected and isinstance(
+                pkt, (Publish, Subscribe)
+            ):
+                # verdicts are scoped to THIS packet: always
+                # reset so nothing stale survives a has_slow
+                # flip or an unconsumed rewrite miss
+                self.channel.preauthz = {}
+                self.channel.presub_filters = None
+            if self.channel.connected and isinstance(
+                pkt, (Publish, Subscribe)
+            ) and self.server.broker.hooks.has_slow("client.authorize"):
+                # a network-backed authz source (or exhook) is
+                # installed: pre-resolve the verdicts OFF-loop so
+                # a backend stall pushes back on this connection
+                # only, never the broker loop (same pattern as
+                # the authenticate fold above)
+                cid = self.channel.client_id
+                hooks = self.server.broker.hooks
+                if isinstance(pkt, Publish):
+                    t = pkt.topic or self.channel.topic_aliases.get(
+                        pkt.props.get("topic_alias")
+                    )
+                    if t:
+                        self.channel.preauthz = await unstaged(
+                            asyncio.get_running_loop().run_in_executor(
+                                None,
+                                lambda: {
+                                    ("publish", t): hooks.run_fold(
+                                        "client.authorize",
+                                        (cid, "publish", t),
+                                        True,
+                                    )
+                                },
+                            )
+                        )
+                else:
+                    # run the client.subscribe fold HERE (once,
+                    # off-loop) so rewritten filters get their
+                    # verdicts pre-resolved too; the channel
+                    # consumes the folded list instead of re-
+                    # running the chain (presub)
+                    def _presub(pkt=pkt):
+                        acc = hooks.run_fold(
+                            "client.subscribe", (cid,), pkt.filters
+                        )
+                        filters = (
+                            acc if acc is not None else pkt.filters
+                        )
+                        verdicts = {
+                            ("subscribe", f): hooks.run_fold(
+                                "client.authorize",
+                                (cid, "subscribe", f),
+                                True,
+                            )
+                            for f, _o in filters
+                        }
+                        return filters, verdicts
+                    (
+                        self.channel.presub_filters,
+                        self.channel.preauthz,
+                    ) = await unstaged(
+                        asyncio.get_running_loop().run_in_executor(
+                            None, _presub
+                        )
+                    )
+            try:
+                out = self.channel.handle_packet(pkt)
+            except ProtocolError as e:
+                if self.channel.proto_ver == MQTT_V5:
+                    self._send_packets([Disconnect(e.code)])
+                raise
+            if out:
+                self._send_packets(out)
+            if self.channel.pending_publish is not None:
+                p = self.channel.take_publish()
+                if p is not None:
+                    self._acks.append(p)
+                    p[0].add_done_callback(self._send_acks)
+            self._wire_sink()
+        return True
 
     async def drain(self) -> None:
         try:

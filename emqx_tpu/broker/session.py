@@ -239,7 +239,7 @@ class Session:
         # ack_sweep stage mark: the sampler buckets stacks caught in
         # this window-advance walk under the ack sweep sub-stage (the
         # wall time is measured by the channel's sampled ack clock)
-        STAGE_MARK.stage = "ack_sweep"
+        prev = STAGE_MARK.enter("ack_sweep")
         out: List[Publish] = []
         led, slot = self._ledger, self._dslot
         while self.mqueue:
@@ -264,7 +264,7 @@ class Session:
                 msg, "puback" if msg.qos == 1 else "pubrec", now
             )
             out.append(self._to_publish(msg, pid))
-        STAGE_MARK.stage = ""
+        STAGE_MARK.leave(prev)
         return out
 
     # --- outgoing acks --------------------------------------------------
@@ -299,7 +299,7 @@ class Session:
 
     def retry(self, now: Optional[float] = None) -> List[Publish]:
         """Re-send unacked QoS1/2 after retry_interval (dup=1)."""
-        STAGE_MARK.stage = "ack_sweep"
+        prev = STAGE_MARK.enter("ack_sweep")
         now = now if now is not None else time.time()
         out = []
         for pid, phase in self._ledger.retry_due(
@@ -315,7 +315,7 @@ class Session:
                 p.dup = True
                 out.append(p)
             # phase 'pubcomp': PUBREL retransmit handled by channel
-        STAGE_MARK.stage = ""
+        STAGE_MARK.leave(prev)
         return out
 
     # --- incoming QoS2 --------------------------------------------------

@@ -413,9 +413,9 @@ class ChaosEngine:
             # storm_gen mark: topic draw + Message construction is the
             # generator's own cost, not the broker's — bucket it so the
             # profiler's `other` bin stops absorbing the storm itself
-            STAGE_MARK.stage = "storm_gen"
+            prev = STAGE_MARK.enter("storm_gen")
             msgs = [Message(topic=t, payload=payload) for t in draw(chunk)]
-            STAGE_MARK.stage = ""
+            STAGE_MARK.leave(prev)
             fut = eng.submit_many(msgs)
             n_sent = len(msgs)
             t_sub = time.monotonic()

@@ -1336,8 +1336,7 @@ class ManagementApi:
                 "breaker": es["breaker"],
                 "admission": es["admission"],
                 "coalesce_factor": es["coalesce_factor"],
-                # device-occupancy timeline: per-slot launch->land
-                # spans, gaps, and the ring busy-ratio (ISSUE 17)
+                # ring slot timeline: per-slot launch->land spans
                 "ring": es.get("ring"),
             }
         ll = getattr(self.obs, "loop_lag", None)

@@ -365,8 +365,10 @@ class DeviceTable:
         shape = (b, int(meta.plen.shape[0]), int(slots.fp.shape[0]))
         self.telemetry.record_shape("match_ids_hash", shape + (mh,))
         dev = hash_ops.match_ids_hash(meta, slots, enc, max_hits=mh)
-        STAGE_MARK.stage = "ticket_start"
-        return (enc, mh, shape, transfer_ops.start_fetch(dev, self.telemetry))
+        prev = STAGE_MARK.enter("ticket_start")
+        ticket = transfer_ops.start_fetch(dev, self.telemetry)
+        STAGE_MARK.leave(prev)
+        return (enc, mh, shape, ticket)
 
     def match_hash_finish(self, pending):
         """Force a begun hash match, escalating once on compaction
@@ -402,8 +404,10 @@ class DeviceTable:
         shape = (b, int(filters.words.shape[0]))
         self.telemetry.record_shape("match_ids", shape + (mh,))
         dev = match_ops.match_ids(filters, enc, max_hits=mh)
-        STAGE_MARK.stage = "ticket_start"
-        return (enc, filters, mh, shape, transfer_ops.start_fetch(dev, self.telemetry))
+        prev = STAGE_MARK.enter("ticket_start")
+        ticket = transfer_ops.start_fetch(dev, self.telemetry)
+        STAGE_MARK.leave(prev)
+        return (enc, filters, mh, shape, ticket)
 
     def match_ids_finish(self, pending):
         """Force a begun dense match, escalating once on overflow.
@@ -436,7 +440,6 @@ class _PendingMatch:
         "topics",       # the sub-batch actually sent to the kernels
         "enc",          # EncodedTopics of `topics` (pow2-padded)
         "out",          # per-sub-topic result lists (exact-deep prefilled)
-        "root",         # telemetry root span (or None)
         "mode",         # cached | host | hash | dense
         "gen",          # router generation captured before the kernels
         "full_out",     # full-batch skeleton when the match cache fronted it
@@ -1788,10 +1791,6 @@ class Router:
         if fi is not None:
             fi.check("match_begin")
         tel.count("dispatch_batches_total")
-        root = tel.span("xla.match_batch")
-        if root is not None:
-            root.set("batch", len(sub))
-        p.root = root
         self.device_table.sync()
         if self._quarantined:
             self._maybe_unquarantine()
@@ -1802,9 +1801,7 @@ class Router:
         # (sync, cache bookkeeping). Saved/restored so non-engine
         # callers keep whatever stage was live.
         mark = STAGE_MARK
-        prev_stage = mark.stage
-        mark.stage = "encode"
-        sp = tel.span("xla.encode", root)
+        prev_stage = mark.enter("encode")
         t0 = clock()
         # the batch axis pads to the next pow2 with inert topics (zero
         # levels, $-rooted: match NOTHING by the length + $-root rules)
@@ -1820,7 +1817,6 @@ class Router:
         tel.record_dispatch(LEG_ENCODE, enc_dt)
         if span is not None:
             span.add("encode", enc_dt)
-        tel.end_span(sp)
         # exact topics are device rows (wildcard-free classes), so the
         # kernel surfaces them; only too-deep exacts need the host dict
         if self._exact_deep:
@@ -1830,7 +1826,7 @@ class Router:
         # ONE launch path for both table kinds: DeviceTable and
         # ShardedDeviceTable expose the same match_{hash,ids}_begin/
         # finish halves (each begin also starts its result transfer)
-        mark.stage = "launch"
+        mark.enter("launch")
         ix = self.index
         if ix is not None:
             p.mode = "hash"
@@ -1847,7 +1843,7 @@ class Router:
                     enc, residual=True
                 )
                 p.residual_elapsed = clock() - t0
-            mark.stage = prev_stage
+            mark.leave(prev_stage)
             if span is not None and p.hash_elapsed is not None:
                 span.add("kernel", p.hash_elapsed)
             return p
@@ -1855,7 +1851,7 @@ class Router:
         t0 = clock()
         p.dense_pending = self.device_table.match_ids_begin(enc)
         p.dense_elapsed = clock() - t0
-        mark.stage = prev_stage
+        mark.leave(prev_stage)
         if span is not None:
             span.add("kernel", p.dense_elapsed)
         return p
@@ -1885,11 +1881,9 @@ class Router:
             if fi is not None:
                 fi.check("match_finish")
         if p.mode == "hash":
-            root = p.root
             ix = self.index
             host_fallback = False
             if p.hash_pending is not None:
-                sp = tel.span("xla.dispatch", root)
                 t0 = clock()
                 ti, bi, amb = self.device_table.match_hash_finish(
                     p.hash_pending
@@ -1897,7 +1891,6 @@ class Router:
                 tel.record_dispatch(
                     LEG_HASH, p.hash_elapsed + clock() - t0
                 )
-                tel.end_span(sp)
                 if amb:
                     # >1 lane of one pair passed the full-fingerprint
                     # check: distinct filters colliding on all 32 bits
@@ -1907,7 +1900,6 @@ class Router:
                     tel.count("ambiguous_batches_total")
                     host_fallback = True
                 else:
-                    sp = tel.span("xla.unpack", root)
                     t0 = clock()
                     twords: List = [None] * len(topics)
                     for t_idx, bid in zip(ti, bi):
@@ -1922,10 +1914,8 @@ class Router:
                             for row in ix.bucket_rows(bid):
                                 out[t_idx].append(self._row_filter[row])
                     tel.record_dispatch(LEG_UNPACK, clock() - t0)
-                    tel.end_span(sp)
             if host_fallback:
                 tel.count("host_fallback_total")
-                sp = tel.span("xla.host_fallback", root)
                 t0 = clock()
                 for i, t in enumerate(topics):
                     # indexed exact topics are NOT in the trie — the
@@ -1935,9 +1925,7 @@ class Router:
                     for row in self._host_trie().match(topic_mod.words(t)):
                         out[i].append(self._row_filter[row])
                 tel.record_dispatch(LEG_FALLBACK, clock() - t0)
-                tel.end_span(sp)
             elif p.residual_pending is not None:
-                sp = tel.span("xla.dispatch", root)
                 t0 = clock()
                 ti, ri = self.device_table.match_ids_finish(
                     p.residual_pending
@@ -1949,10 +1937,7 @@ class Router:
                 tel.record_dispatch(
                     LEG_DENSE, p.residual_elapsed + clock() - t0
                 )
-                tel.end_span(sp)
         elif p.mode == "dense":
-            root = p.root
-            sp = tel.span("xla.dispatch", root)
             t0 = clock()
             ti, ri = self.device_table.match_ids_finish(p.dense_pending)
             b = len(topics)
@@ -1960,7 +1945,6 @@ class Router:
                 if t_idx < b:  # drop pow2/dp padding rows
                     out[int(t_idx)].append(self._row_filter[int(row)])
             tel.record_dispatch(LEG_DENSE, p.dense_elapsed + clock() - t0)
-            tel.end_span(sp)
         if p.mode not in ("cached", "host"):
             # (host mode already folded deep matches via match_filters
             # and needs no quarantine overlay: it IS host truth)
@@ -1971,7 +1955,6 @@ class Router:
                 self._quarantine_overlay(topics, out)
             if self._suspended_shards and out:
                 self._shard_overlay(topics, out)
-            tel.end_span(p.root)
         if span is not None:
             # transfer = residual device->host wait the tickets
             # actually blocked for (zero when the eager copies landed

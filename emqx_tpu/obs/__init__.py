@@ -55,6 +55,7 @@ from .topic_metrics import TopicMetrics  # noqa: F401
 from .profiler import (  # noqa: F401
     DELIVERY_STAGES,
     STAGE_MARK,
+    STAGES,
     LoopLagMonitor,
     SamplingProfiler,
 )

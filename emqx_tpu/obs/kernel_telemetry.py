@@ -31,8 +31,7 @@ path reports into:
 Export surfaces: `prometheus_lines()` renders `emqx_xla_*` families
 (histograms with `_bucket`/`_sum`/`_count` + `le` labels) appended to
 the broker scrape; `snapshot()` is the JSON body of
-GET /api/v5/xla/telemetry; an optional `tracer` (obs/otel.py Tracer)
-receives encode→dispatch→unpack spans per batch.
+GET /api/v5/xla/telemetry.
 
 `NullKernelTelemetry` keeps the hot path branch-free when disabled:
 every record method is a bound no-op and `clock` returns 0.0 without a
@@ -228,15 +227,11 @@ class KernelTelemetry:
     enabled = True
     clock = staticmethod(perf_counter)
 
-    def __init__(self, tracer=None, retrace_warn_after: int = 16):
-        # spans flow through the obs/otel.py Tracer seam when attached
-        # (None costs one attribute read per batch, same contract as
-        # broker.tracer)
-        self.tracer = tracer
+    def __init__(self, retrace_warn_after: int = 16):
         # flight-recorder seam (obs/flight_recorder.FlightRecorder):
         # when attached, every dispatch-leg sample also lands in the
-        # ring as an `xla.<leg>` event — the same stage names as the
-        # histograms/spans — so the black box can answer "what were
+        # ring as an `xla.<leg>` event — the same leg names as the
+        # histograms — so the black box can answer "what were
         # the device legs doing right before the breach". None costs
         # one attribute read per record.
         self.flight = None
@@ -259,7 +254,6 @@ class KernelTelemetry:
         ] = {}
         self.gauges: Dict[str, float] = {}
         self._shape_keys: Dict[str, Set[tuple]] = {}
-        self._trace_seq = 0
         # serve-time retrace accounting: False during AOT warmup (the
         # engine pre-traces every shape bucket at attach), True once
         # mark_serving() flips it — a fresh shape key after that is a
@@ -440,26 +434,6 @@ class KernelTelemetry:
                 round(len(ix) / ix.n_slots, 6) if ix.n_slots else 0.0,
             )
 
-    # --- spans (encode -> dispatch -> unpack) -----------------------------
-
-    def span(self, name: str, parent=None):
-        """Start a child span under `parent` (or a new trace) through
-        the attached Tracer; returns None when no tracer is wired so
-        hot-path callers pay one attribute read."""
-        tr = self.tracer
-        if tr is None:
-            return None
-        if parent is not None:
-            trace_id = parent.trace_id
-        else:
-            self._trace_seq += 1
-            trace_id = f"{self._trace_seq:032x}"
-        return tr.start_span(name, trace_id, parent)
-
-    def end_span(self, span) -> None:
-        if span is not None:
-            self.tracer.finish(span)
-
     # --- export -----------------------------------------------------------
 
     def snapshot(self) -> Dict[str, Any]:
@@ -539,7 +513,6 @@ class NullKernelTelemetry:
     path, no syscalls (clock returns 0.0), no state."""
 
     enabled = False
-    tracer = None
     flight = None
 
     @staticmethod
@@ -586,12 +559,6 @@ class NullKernelTelemetry:
         pass
 
     def observe_device_table(self, dtable) -> None:
-        pass
-
-    def span(self, name, parent=None):
-        return None
-
-    def end_span(self, span) -> None:
         pass
 
     def snapshot(self) -> Dict[str, Any]:

@@ -72,6 +72,16 @@ def trace_id_of(msg) -> str:
     return trace_id_of_str(str(h))
 
 
+def publish_root(tracer: Tracer, msg) -> Span:
+    """Open a publish's `mqtt.publish` root span: the one schema that
+    the host publish and the dispatch engine share."""
+    root = tracer.start_span("mqtt.publish", trace_id_of(msg), None)
+    root.set("mqtt.topic", msg.topic).set("mqtt.qos", msg.qos)
+    if msg.from_client:
+        root.set("mqtt.clientid", msg.from_client)
+    return root
+
+
 def trace_id_of_str(h: str) -> str:
     """Raw message id -> trace id (the flight recorder stores ids on
     its hot path and derives trace ids only at read/export time)."""

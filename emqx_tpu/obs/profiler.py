@@ -21,14 +21,16 @@ without per-call probes (the PR 2/PR 5 <=2% discipline):
     labeled, that separates "the loop is busy" from "the loop is
     parked in epoll".
 
-  * **STAGE_MARK** — one module-global cell the instrumented delivery
-    path stamps with the sub-stage it is entering (`dispatch_loop`,
-    `session_write`, ...). The hot-path cost is a single attribute
-    store per stage TRANSITION (per batch / per publish, never per
-    subscriber); the sampler reads it to bucket each stack under the
-    sub-stage that was live when the sample hit. The emqx analog is
-    system_monitor's long_schedule attribution: the scheduler tells
-    you WHERE it was when the gap happened.
+  * **STAGE_MARK** — the program's one stage vocabulary (STAGES): a
+    module-global register the event loop's hot path stamps at every
+    stage transition (per read, per batch stage, per delivery leg —
+    never per subscriber). The sampler reads it to bucket each stack
+    under the stage that was live when the sample hit; while a
+    `jax.profiler` trace runs, every transition also closes and opens
+    an `emqx.<stage>` host span, so the program's stages lie on the
+    device trace's clock. The emqx analog is system_monitor's
+    long_schedule attribution: the scheduler tells you WHERE it was
+    when the gap happened.
 
   * **LoopLagMonitor** — the sentinel-stage accounting fix (ISSUE 17
     satellite): `queue` used to absorb event-loop scheduling delay
@@ -44,11 +46,16 @@ snapshot ships with the stacks that caused it.
 
 from __future__ import annotations
 
+import gc
 import sys
 import threading
 import time
 from collections import deque
 from typing import Any, Deque, Dict, List, Optional, Tuple
+
+# jaxlib's TraceMe is what jax.profiler.TraceAnnotation subclasses:
+# imported from jaxlib so this module does not import jax itself
+from jaxlib._profiler import TraceMe
 
 from .kernel_telemetry import StreamingHistogram
 
@@ -75,29 +82,135 @@ MAX_DEPTH = 64
 _OVERFLOW_KEY = ("<overflow>",)
 
 
-class _StageMark:
-    """The one-cell stage register the delivery path stamps and the
-    sampler reads. A plain attribute store/read — no locks: a torn
-    read can only misattribute one sample to a neighboring stage,
-    which the sampling error already dominates."""
+# Every name STAGE_MARK takes, by layer; a running trace records stage
+# `s` as the host span SPAN_PREFIX + s.
+STAGES = (
+    # listener / channel: frame decode of one read, the channel's
+    # handling of its packets, the PUBACK/PUBREC writes
+    "decode", "channel", "ack_write",
+    # dispatch engine: the batch's hook fold, the launch residual
+    # (cache probe, table sync), the result fetch and unpack
+    "coalesce", "match_launch", "match_fetch",
+    # router: topic encode, the jit dispatch, the transfer start
+    "encode", "launch", "ticket_start",
+    # delivery
+    "plan_resolve", "dispatch_loop", "session_write", "ack_sweep",
+    # a garbage collection, nested in whatever stage it interrupted
+    "gc",
+    # the chaos storm generator's own message build
+    "storm_gen",
+)
+SPAN_PREFIX = "emqx."
 
-    __slots__ = ("stage",)
+_tracing = TraceMe.is_enabled
+
+
+class _StageMark:
+    """The stage register. `stage` is the live stage: a plain attribute
+    the sampler thread reads without a lock (a torn read misattributes
+    one sample, which the sampling error already dominates).
+
+    `enter(name)` makes `name` live and returns the stage it replaced;
+    `leave(prev)` restores it. Stages are flat: a nested stage saves
+    its parent and restores it on exit, so at most one span is open
+    and a stage's time is the sum of its segments (its self time).
+    While a `jax.profiler` trace runs, each transition on the bound
+    thread (the broker's event loop) ends the open `emqx.*` span and
+    starts the new stage's; `span` is the open one, for arguments.
+    With no trace running a transition is one store and one
+    `TraceMe.is_enabled()` call: no clock read, no allocation."""
+
+    __slots__ = (
+        "stage", "span", "thread", "_attached", "_gc_prev", "_respanning",
+    )
 
     def __init__(self) -> None:
         self.stage = ""
+        self.span = None
+        self.thread = threading.main_thread().ident
+        self._attached = 0
+        self._gc_prev = ""
+        self._respanning = False
+
+    def enter(self, name: str) -> str:
+        prev = self.stage
+        self.stage = name
+        if self.span is not None or (name and _tracing()):
+            self._respan(name)
+        return prev
+
+    def leave(self, prev: str) -> None:
+        self.stage = prev
+        if self.span is not None or (prev and _tracing()):
+            self._respan(prev)
+
+    def _respan(self, name: str) -> None:
+        if self._respanning or threading.get_ident() != self.thread:
+            # a worker thread's stages stay off the loop's line; a
+            # collection inside a transition (the `gc` hook re-entering
+            # here) moves the stage but opens no span of its own
+            return
+        self._respanning = True
+        try:
+            sp = self.span
+            if sp is not None:
+                self.span = None
+                sp.__exit__(None, None, None)
+            if name and TraceMe.is_enabled():
+                sp = TraceMe(SPAN_PREFIX + name)
+                sp.__enter__()
+                self.span = sp
+        finally:
+            self._respanning = False
+
+    def attach(self) -> None:
+        """Bind spans to the calling thread (the broker's event loop)
+        and install the `gc` stage hook. Paired with detach()."""
+        self.thread = threading.get_ident()
+        self._attached += 1
+        if self._attached == 1:
+            gc.callbacks.append(self._on_gc)
+
+    def detach(self) -> None:
+        if self._attached == 0:
+            return
+        self._attached -= 1
+        if self._attached == 0 and self._on_gc in gc.callbacks:
+            gc.callbacks.remove(self._on_gc)
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        if threading.get_ident() != self.thread:
+            return  # another thread's collection is not the loop's stage
+        if phase == "start":
+            self._gc_prev = self.enter("gc")
+            sp = self.span
+            if sp is not None and not self._respanning:
+                sp.set_metadata(generation=info["generation"])
+        else:
+            self.leave(self._gc_prev)
 
 
-# module-global: broker/pubsub + dispatch_engine import this once and
-# stamp `.stage`; the sampler thread reads it per sample
+# module-global: the listener, engine, router and delivery path import
+# this once and stamp it; the sampler thread reads `.stage` per sample
 STAGE_MARK = _StageMark()
+
+
+async def unstaged(aw):
+    """Await `aw` with no stage live: while a task is suspended, the
+    loop's time is not the suspended task's stage."""
+    prev = STAGE_MARK.enter("")
+    try:
+        return await aw
+    finally:
+        STAGE_MARK.leave(prev)
 
 
 class SamplingProfiler:
     """Thread-based wall+CPU stack sampler over the event-loop thread.
 
     `start()` spawns one daemon thread; `stop()` joins it. While
-    stopped the served path pays zero (no hooks are installed —
-    ever). Aggregation: stack tuple (outermost..innermost
+    stopped the served path pays nothing for it (the sampler installs
+    no hooks). Aggregation: stack tuple (outermost..innermost
     "module:func:line" frames) -> [wall_samples, cpu_samples], bucketed
     under the STAGE_MARK sub-stage live at sample time ("" = outside
     the delivery path)."""

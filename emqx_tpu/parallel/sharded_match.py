@@ -1126,8 +1126,9 @@ class ShardedDeviceTable:
         out = self._match_kernel(mh)(dev, t_dev)
         if rec is not None:
             sc.lap(rec, "program_launch")
-        STAGE_MARK.stage = "ticket_start"
+        prev = STAGE_MARK.enter("ticket_start")
         ticket = transfer_ops.start_fetch(out, self.telemetry)
+        STAGE_MARK.leave(prev)
         if rec is not None:
             sc.attach(rec, ticket)
         return (dev, t_dev, mh, rec, ticket)
@@ -1213,8 +1214,9 @@ class ShardedDeviceTable:
         out = self._hash_kernel(mh)(self._dev_meta, self._dev_slots, t_dev)
         if rec is not None:
             sc.lap(rec, "program_launch")
-        STAGE_MARK.stage = "ticket_start"
+        prev = STAGE_MARK.enter("ticket_start")
         ticket = transfer_ops.start_fetch(out, self.telemetry)
+        STAGE_MARK.leave(prev)
         if rec is not None:
             sc.attach(rec, ticket)
         return (t_dev, mh, rec, ticket)

@@ -12,9 +12,7 @@ Four surfaces under test:
     (`_deliver_plan_timed`) must produce byte-identical sink output
     to the untimed hot loop it mirrors — the instrumentation can
     never change what subscribers receive;
-  * the device-occupancy timeline: per-slot launch->land spans, gap
-    accounting over idle windows, and a busy-ratio that stays a
-    ratio;
+  * the ring slot timeline: per-slot launch->land spans;
   * the sampling profiler + loop-lag ticker: probe-free stack
     attribution with bounded tables, collapsed-stack output, bounded
     auto-arm; and the lag ticker that keeps co-tenant scheduling
@@ -183,7 +181,6 @@ async def test_ring_occupancy_timeline():
     await eng.stop()
     ring = eng.ring_status()
     assert ring["slots_total"] >= 2
-    assert 0.0 < ring["occupancy_ratio"] <= 1.0
     assert ring["timeline"], "no slot spans recorded"
     for slot in ring["timeline"]:
         assert set(slot) == {"launch", "land", "span_ms", "mode",
@@ -193,9 +190,6 @@ async def test_ring_occupancy_timeline():
     tel = broker.router.telemetry
     assert tel.family_hist["ring_slot_span_seconds"].total == \
         ring["slots_total"]
-    # the idle window between the waves landed in the gap histogram
-    assert tel.family_hist["ring_gap_seconds"].total >= 1
-    assert tel.family_hist["ring_gap_seconds"].percentile(99) >= 0.1
 
 
 async def test_loop_lag_monitor():

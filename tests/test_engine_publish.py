@@ -264,6 +264,10 @@ async def test_one_connection_keeps_many_qos1_in_flight(tmp_path):
 
 
 async def test_traced_publish_takes_host_path_and_is_counted(tmp_path):
+    """With an external tracer set, a listener publish is served by a
+    device batch, the traced-host-path counter stays 0, and the engine
+    leaves the host publish's mqtt.publish / broker.route /
+    broker.dispatch spans, under one root per publish."""
     node, port = await boot(tmp_path)
     try:
         from emqx_tpu.obs.otel import MemoryTracer
@@ -277,9 +281,25 @@ async def test_traced_publish_takes_host_path_and_is_counted(tmp_path):
         assert (await pub.expect(Puback)).code == 0
         assert (await sub.expect(Publish)).payload == b"x"
         counters = node.broker.router.telemetry.counters
-        assert counters.get("traced_host_publish_total", 0) == 1
-        assert batches(node) == b0
-        assert "mqtt.publish" in {sp.name for sp in tracer.spans}
+        assert counters.get("traced_host_publish_total", 0) == 0
+        assert batches(node) == b0 + 1
+        by_name = {}
+        for sp in tracer.spans:
+            by_name.setdefault(sp.name, []).append(sp)
+        (root,) = [
+            r for r in by_name["mqtt.publish"]
+            if r.attrs.get("mqtt.topic") == "tr/1"
+        ]
+        assert root.attrs["mqtt.qos"] == 1
+        assert root.attrs["mqtt.clientid"] == "pub"
+        assert root.attrs["mqtt.deliveries"] == 1
+        (route,) = [s for s in by_name["broker.route"] if s.parent_id == root.span_id]
+        (disp,) = [s for s in by_name["broker.dispatch"] if s.parent_id == root.span_id]
+        assert route.attrs["broker.matched_filters"] == 1
+        assert disp.attrs["broker.deliveries"] == 1
+        assert route.trace_id == disp.trace_id == root.trace_id
+        assert root.start_ns <= route.start_ns <= route.end_ns
+        assert route.end_ns <= disp.start_ns <= disp.end_ns <= root.end_ns
         pub.close()
         sub.close()
     finally:

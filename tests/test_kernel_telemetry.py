@@ -145,24 +145,6 @@ def test_escalation_counter_on_dense_overflow():
     assert tel.histogram("dense").total >= 1
 
 
-def test_spans_emitted_through_tracer():
-    from emqx_tpu.obs.otel import MemoryTracer
-
-    r = _routed()
-    mt = MemoryTracer()
-    r.telemetry.tracer = mt
-    r.match_filters_batch([f"t{i}/a/x/y" for i in range(4)])
-    names = [s.name for s in mt.spans]
-    assert "xla.encode" in names
-    assert "xla.dispatch" in names
-    assert "xla.match_batch" in names
-    root = next(s for s in mt.spans if s.name == "xla.match_batch")
-    children = [s for s in mt.spans if s.parent_id == root.span_id]
-    assert children, "stage spans must parent to the batch root"
-    assert all(s.trace_id == root.trace_id for s in children)
-    assert root.attrs["batch"] == 4
-
-
 # --- null collector -------------------------------------------------------
 
 

@@ -998,13 +998,13 @@ def test_mesh_scope_families_lint():
 
 async def test_delivery_stage_ring_and_profiler_families_lint(tmp_path):
     """ISSUE-17 families: the queue-stage sub-decomposition
-    (emqx_xla_delivery_*), the device-occupancy timeline
-    (emqx_xla_ring_*), the sampling profiler counters/gauges
+    (emqx_xla_delivery_*), the ring slot timeline
+    (emqx_xla_ring_slot_span_seconds), the sampling profiler counters/gauges
     (emqx_xla_profiler_*), and the event-loop lag histogram
     (emqx_xla_loop_lag_seconds) must all render on ONE scrape driven
     through a REAL dense-sampled engine run — mixed QoS so every one
     of the six sub-stages records, two publish waves separated by an
-    idle window so the ring-gap histogram moves — and pass the same
+    idle window — and pass the same
     exposition lint. Never hand-poked counters."""
     from emqx_tpu.obs import Observability
     from emqx_tpu.obs.profiler import DELIVERY_STAGES
@@ -1032,7 +1032,7 @@ async def test_delivery_stage_ring_and_profiler_families_lint(tmp_path):
         await asyncio.gather(
             *[eng.publish(Message(topic=t, payload=b"x")) for t in topics]
         )
-        await asyncio.sleep(0.15)  # ring idles: next launch records a gap
+        await asyncio.sleep(0.15)  # the ring idles between the waves
         await asyncio.gather(
             *[eng.publish(Message(topic=t, payload=b"y")) for t in topics]
         )
@@ -1048,7 +1048,6 @@ async def test_delivery_stage_ring_and_profiler_families_lint(tmp_path):
         # the ring saw multiple slots and the idle window
         ring = eng.ring_status()
         assert ring["slots_total"] >= 2
-        assert 0.0 < ring["occupancy_ratio"] <= 1.0
 
         text = obs.prometheus_text()
         types = _lint(text)
@@ -1059,8 +1058,6 @@ async def test_delivery_stage_ring_and_profiler_families_lint(tmp_path):
             ("emqx_xla_delivery_decomp_out_of_band_total", "counter"),
             ("emqx_xla_delivery_decomp_last_ratio", "gauge"),
             ("emqx_xla_ring_slot_span_seconds", "histogram"),
-            ("emqx_xla_ring_gap_seconds", "histogram"),
-            ("emqx_xla_ring_occupancy_ratio", "gauge"),
             ("emqx_xla_loop_lag_seconds", "histogram"),
             ("emqx_xla_profiler_samples_total", "counter"),
             ("emqx_xla_profiler_cpu_samples_total", "counter"),
@@ -1106,12 +1103,6 @@ async def test_delivery_stage_ring_and_profiler_families_lint(tmp_path):
             "fan _sum rendered with seconds-style nanosecond padding: "
             f"{m.group(1) if m else None}"
         )
-        # the gap histogram caught the idle window between the waves
-        m = re.search(
-            r'emqx_xla_ring_gap_seconds_count\{node="n1@host"\} (\d+)',
-            text,
-        )
-        assert m and int(m.group(1)) >= 1
         # the profiler took samples while armed over the drive
         m = re.search(
             r'emqx_xla_profiler_samples_total\{node="n1@host"\} (\d+)',

@@ -584,7 +584,7 @@ def test_delivery_stages_have_recording_sites_and_lint_coverage():
     """No orphan sub-stages (ISSUE 17): every stage name in
     obs/profiler.DELIVERY_STAGES must (a) be RECORDED somewhere on the
     dispatch path — a `span.add_sub("<stage>", ...)` /
-    `observe_delivery("<stage>", ...)` fold or a `STAGE_MARK` stamp —
+    `observe_delivery("<stage>", ...)` fold or a `STAGE_MARK.enter` —
     outside the module that merely declares the tuple, and (b) appear
     in the prometheus lint suite, which drives the
     emqx_xla_delivery_stage_seconds family on a live scrape. A stage
@@ -607,7 +607,7 @@ def test_delivery_stages_have_recording_sites_and_lint_coverage():
         recorded = any(
             f'add_sub("{stage}"' in text
             or f'observe_delivery("{stage}"' in text
-            or f'.stage = "{stage}"' in text
+            or f'.enter("{stage}")' in text
             for text in corpus.values()
         )
         if not recorded:
