@@ -113,17 +113,18 @@ def make_scan_bench(jax, jnp, match_ids_hash, max_hits, gen_topics, k):
     the match over them.  Returns (total, checksum): the checksum
     keeps the compaction from being dead-code eliminated, and only two
     scalars cross the wire."""
-    from emqx_tpu.ops.match import EncodedTopics
+    from emqx_tpu.ops.hash_index import split_hash_result
+    from emqx_tpu.ops.match import EncodedTopics, PackedTopics
 
     @jax.jit
     def many(meta, slots, aux, seed):
         ids, lens, dollar = gen_topics(jax.random.PRNGKey(seed), aux)
 
         def one(carry, xs):
-            enc = EncodedTopics(xs[0], xs[1], xs[2])
-            ti, bi, total, amb = match_ids_hash(
+            enc = PackedTopics.of(EncodedTopics(xs[0], xs[1], xs[2]))
+            ti, bi, total, amb = split_hash_result(match_ids_hash(
                 meta, slots, enc, max_hits=max_hits
-            )
+            ), max_hits)
             chk = (ti * jnp.int32(1315423911) + bi).sum(
                 dtype=jnp.int32
             ) + amb * jnp.int32(7919)
@@ -412,8 +413,8 @@ def bench_1m(jax, jnp, floor, details):
     from emqx_tpu.ops import hash_index as H
     from emqx_tpu.ops import native_baseline as NB
     from emqx_tpu.ops import topic as topic_mod
-    from emqx_tpu.ops.hash_index import ClassIndex, match_ids_hash
-    from emqx_tpu.ops.match import EncodedTopics
+    from emqx_tpu.ops.hash_index import ClassIndex, match_ids_hash, split_hash_result
+    from emqx_tpu.ops.match import EncodedTopics, PackedTopics
     from emqx_tpu.ops.table import FilterTable
 
     # K=256 batches per dispatch: enough kernel work per dispatch that
@@ -505,13 +506,14 @@ def bench_1m(jax, jnp, floor, details):
             (f"t{d % 997}", f"r{d % 13}", f"d{d}", "x9", "m", "temp")
         ):
             ids[j, i] = lk(w)
-    enc = EncodedTopics(
+    enc = PackedTopics.of(EncodedTopics(
         jnp.asarray(ids),
         jnp.asarray(np.full(B, 6, np.int32)),
         jnp.asarray(np.zeros(B, bool)),
-    )
-    ti, bi, tot, amb = match_ids_hash(meta, slots, enc, max_hits=4096)
-    ti, bi, tot = np.asarray(ti), np.asarray(bi), int(tot)
+    ))
+    ti, bi, tot, amb = split_hash_result(
+        np.asarray(match_ids_hash(meta, slots, enc, max_hits=4096)), 4096)
+    tot = int(tot)
     if int(amb):
         # amb now also counts benign >2 probe-byte coincidences
         # (~1e-4/pair — the two-lane verify's host-fallback contract,
@@ -527,13 +529,14 @@ def bench_1m(jax, jnp, floor, details):
                 (f"t{d % 997}", f"r{d % 13}", f"d{d}", "x9", "m", "temp")
             ):
                 ids[j, i] = lk(w)
-        enc = EncodedTopics(
+        enc = PackedTopics.of(EncodedTopics(
             jnp.asarray(ids),
             jnp.asarray(np.full(B, 6, np.int32)),
             jnp.asarray(np.zeros(B, bool)),
-        )
-        ti, bi, tot, amb = match_ids_hash(meta, slots, enc, max_hits=4096)
-        ti, bi, tot = np.asarray(ti), np.asarray(bi), int(tot)
+        ))
+        ti, bi, tot, amb = split_hash_result(
+            np.asarray(match_ids_hash(meta, slots, enc, max_hits=4096)), 4096)
+        tot = int(tot)
         topics_s = [f"t{d % 997}/r{d % 13}/d{d}/x9/m/temp" for d in ds]
     assert int(amb) == 0, "ambiguity in two consecutive exactness batches"
     got = [set() for _ in range(B)]
@@ -570,18 +573,18 @@ def bench_1m(jax, jnp, floor, details):
                 (f"t{d % 997}", f"r{d % 13}", f"d{d}", f"x{k}", "m", "temp")
             ):
                 ids_k[j, i] = lk(w)
-        e2e_encs.append(EncodedTopics(
+        e2e_encs.append(PackedTopics.of(EncodedTopics(
             jnp.asarray(ids_k),
             jnp.asarray(np.full(B, 6, np.int32)),
             jnp.asarray(np.zeros(B, bool)),
-        ))
+        )))
 
     def e2e_launch(j):
         # SAME max_hits as the kernel-resident measurement above, so
         # the e2e delta is pure transfer/RTT, not extra buffer work
-        return match_ids_hash(
+        return (match_ids_hash(
             meta, slots, e2e_encs[j % len(e2e_encs)], max_hits=2048
-        )
+        ),)
 
     # AOT warm the exact dispatch+fetch shape, then flip the collector
     # to serving: any retrace inside the timed windows is counted —
@@ -959,13 +962,13 @@ def bench_10m(jax, jnp, floor, details):
 
     # end-to-end: one dispatch + device->host transfer of the pairs
     # (the broker-visible latency; see the config-2 e2e note)
-    from emqx_tpu.ops.match import EncodedTopics as _ET
+    from emqx_tpu.ops.match import EncodedTopics as _ET, PackedTopics as _PT
 
     @jax.jit
     def one_batch(meta_, slots_, aux_, seed):
         ids, lens, dollar = gen_topics(jax.random.PRNGKey(seed), aux_)
-        enc1 = _ET(ids[0], lens[0], dollar[0])
-        return match_ids_hash(meta_, slots_, enc1, max_hits=2048)
+        enc1 = _PT.of(_ET(ids[0], lens[0], dollar[0]))
+        return (match_ids_hash(meta_, slots_, enc1, max_hits=2048),)
 
     aux3 = (skel_dev, plen_c, plus_c, hash_c)
     one_batch(meta, slots, aux3, 1)  # compile (AOT warm)
@@ -1063,8 +1066,8 @@ def bench_10m(jax, jnp, floor, details):
 
 
 def bench_shared(jax, jnp, floor, details, state):
-    from emqx_tpu.ops.hash_index import match_ids_hash
-    from emqx_tpu.ops.match import EncodedTopics
+    from emqx_tpu.ops.hash_index import match_ids_hash, split_hash_result
+    from emqx_tpu.ops.match import EncodedTopics, PackedTopics
 
     table, index, meta, slots = state
     L, B, K, N = 8, 1024, 64, (1 << 20) // SHRINK
@@ -1092,12 +1095,12 @@ def bench_shared(jax, jnp, floor, details, state):
         ids = ids.at[..., 5].set(junk ^ 7)
 
         def one(carry, xs):
-            enc = EncodedTopics(
+            enc = PackedTopics.of(EncodedTopics(
                 xs[0], jnp.full((B,), 6, jnp.int32), jnp.zeros((B,), bool)
-            )
-            ti, bi, total, amb = match_ids_hash(
+            ))
+            ti, bi, total, amb = split_hash_result(match_ids_hash(
                 meta, slots, enc, max_hits=2048
-            )
+            ), 2048)
             # group-hash member pick ON DEVICE (hash_clientid strategy:
             # the TPU-native fanout design — segment ops, not host loops)
             grp = jnp.where(bi >= 0, bi % G, 0)
@@ -1142,15 +1145,14 @@ def bench_shared(jax, jnp, floor, details, state):
                 (f"t{d % 997}", f"r{d % 13}", f"d{d}", "x9", "m", "temp")
             ):
                 ids[j, i] = lk2(w)
-        enc = EncodedTopics(
+        enc = PackedTopics.of(EncodedTopics(
             jnp.asarray(ids),
             jnp.asarray(np.full(B, 6, np.int32)),
             jnp.asarray(np.zeros(B, bool)),
-        )
+        ))
         f0 = _floor_once(jax, jnp)
         t0 = time.time()
-        ti, bi, tot, _amb = match_ids_hash(meta, slots, enc, max_hits=4096)
-        _ = np.asarray(ti), np.asarray(bi), int(tot)
+        _ = np.asarray(match_ids_hash(meta, slots, enc, max_hits=4096))
         dt = time.time() - t0
         if trial:  # first trial pays compile
             e2e.append(max(dt - min(f0, dt), 1e-5))

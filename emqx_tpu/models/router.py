@@ -355,10 +355,11 @@ class DeviceTable:
             mh = 1 << (cap.bit_length() - 1)
         return mh
 
-    def match_hash_begin(self, enc: match_ops.EncodedTopics):
+    def match_hash_begin(self, enc: match_ops.PackedTopics):
         """Launch the pattern-class hash kernel + begin the result
-        transfer; no host fetch is forced. Returns an opaque handle
-        for match_hash_finish (ticket last)."""
+        transfer; no host fetch is forced. One buffer crosses the link
+        each way: the packed topics in, the packed result out. Returns
+        an opaque handle for match_hash_finish (ticket last)."""
         meta, slots = self.hash_state()
         b = int(enc.ids.shape[0])
         mh = self._cap_hits(max(1024, _next_pow2(2 * b)))
@@ -366,8 +367,9 @@ class DeviceTable:
         self.telemetry.record_shape("match_ids_hash", shape + (mh,))
         dev = hash_ops.match_ids_hash(meta, slots, enc, max_hits=mh)
         prev = STAGE_MARK.enter("ticket_start")
-        ticket = transfer_ops.start_fetch(dev, self.telemetry)
+        ticket = transfer_ops.start_fetch((dev,), self.telemetry)
         STAGE_MARK.leave(prev)
+        self.telemetry.count("transfer_buffers_total", len(enc) + 1)
         return (enc, mh, shape, ticket)
 
     def match_hash_finish(self, pending):
@@ -377,7 +379,7 @@ class DeviceTable:
         ti beyond the live batch (pow2 padding) are the caller's to
         skip, same contract as the sharded finish."""
         enc, mh, shape, ticket = pending
-        ti, bi, total, amb = ticket.wait()
+        ti, bi, total, amb = hash_ops.split_hash_result(ticket.wait()[0], mh)
         total = int(total)
         if total > mh:
             tel = self.telemetry
@@ -385,16 +387,18 @@ class DeviceTable:
             mh = _next_pow2(total)
             tel.record_shape("match_ids_hash", shape + (mh,))
             meta, slots = self.hash_state()
-            ti, bi, _t, amb = transfer_ops.start_fetch(
-                hash_ops.match_ids_hash(meta, slots, enc, max_hits=mh),
-                self.telemetry,
-            ).wait()
-        return np.asarray(ti)[:total], np.asarray(bi)[:total], int(amb)
+            dev = hash_ops.match_ids_hash(meta, slots, enc, max_hits=mh)
+            out = transfer_ops.start_fetch((dev,), tel).wait()[0]
+            tel.count("transfer_buffers_total", len(enc) + 1)
+            ti, bi, _t, amb = hash_ops.split_hash_result(out, mh)
+        return ti[:total], bi[:total], int(amb)
 
-    def match_ids_begin(self, enc: match_ops.EncodedTopics, residual: bool = False):
+    def match_ids_begin(self, enc: match_ops.PackedTopics, residual: bool = False):
         """Launch the dense compaction kernel (full table, or the
         residual unclassed rows) + begin the result transfer. Same
-        handle contract as match_hash_begin."""
+        handle contract as match_hash_begin. The dense kernel takes
+        the batch's three fields, as views of the packed buffer."""
+        enc = enc.fields()
         filters = self.residual_filters() if residual else self.filters()
         b = int(enc.ids.shape[0])
         if residual:
@@ -407,6 +411,7 @@ class DeviceTable:
         prev = STAGE_MARK.enter("ticket_start")
         ticket = transfer_ops.start_fetch(dev, self.telemetry)
         STAGE_MARK.leave(prev)
+        self.telemetry.count("transfer_buffers_total", len(enc) + len(dev))
         return (enc, filters, mh, shape, ticket)
 
     def match_ids_finish(self, pending):
@@ -421,10 +426,9 @@ class DeviceTable:
             tel.count("escalations_total")
             mh = _next_pow2(total)
             tel.record_shape("match_ids", shape + (mh,))
-            ti, ri, _t = transfer_ops.start_fetch(
-                match_ops.match_ids(filters, enc, max_hits=mh),
-                self.telemetry,
-            ).wait()
+            dev = match_ops.match_ids(filters, enc, max_hits=mh)
+            ti, ri, _t = transfer_ops.start_fetch(dev, tel).wait()
+            tel.count("transfer_buffers_total", len(enc) + len(dev))
         return np.asarray(ti)[:total], np.asarray(ri)[:total]
 
 
@@ -438,7 +442,7 @@ class _PendingMatch:
 
     __slots__ = (
         "topics",       # the sub-batch actually sent to the kernels
-        "enc",          # EncodedTopics of `topics` (pow2-padded)
+        "enc",          # PackedTopics of `topics` (pow2-padded)
         "out",          # per-sub-topic result lists (exact-deep prefilled)
         "mode",         # cached | host | hash | dense
         "gen",          # router generation captured before the kernels

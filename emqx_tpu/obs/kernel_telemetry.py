@@ -26,7 +26,11 @@ path reports into:
     sync batch size;
   * escalation/fallback counters: `_escalating_pairs` retries,
     hash-kernel overflow re-dispatches, ambiguity host fallbacks, and
-    rows the pattern-class index couldn't class (residual).
+    rows the pattern-class index couldn't class (residual);
+  * `transfer_buffers_total`: buffers that crossed the host-device
+    link for match launches, both directions (host arrays a kernel
+    takes + result buffers it returns). A hash batch moves 2: the
+    packed topics in, the packed result out.
 
 Export surfaces: `prometheus_lines()` renders `emqx_xla_*` families
 (histograms with `_bucket`/`_sum`/`_count` + `le` labels) appended to

@@ -74,7 +74,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import speedups as _speedups
-from .match import EncodedTopics
+from .match import PackedTopics
 from .table import FilterTable
 from .vocab import PLUS
 
@@ -907,7 +907,7 @@ class ClassIndex:
 def match_ids_hash(
     meta: ClassMeta,
     slots: SlotArrays,
-    topics: EncodedTopics,
+    topics: PackedTopics,
     max_hits: int = 4096,
 ):
     """Probe every (topic, class) pair's TWO cuckoo buckets in one
@@ -924,8 +924,11 @@ def match_ids_hash(
     fingerprint matches), and pairs are the output unit — no per-lane
     compaction pass.
 
-    Returns (topic_idx int32 [max_hits], bucket_id int32 [max_hits],
-    total int32, amb int32). `total` is the EXACT flagged-pair count,
+    Takes the batch as one packed int32 [B, L + 2] buffer and returns
+    ONE int32 [2 * max_hits + 2] buffer, topic_idx | bucket_id | total
+    | amb (`split_hash_result` slices it), so a launch moves one buffer
+    each way across the host-device link. `total` is the EXACT
+    flagged-pair count,
     so on overflow the caller re-runs once with max_hits =
     next_pow2(total). Within the first `total` entries, pairs whose
     full-fingerprint check rejected every lane carry -1/-1 — callers
@@ -937,6 +940,7 @@ def match_ids_hash(
     keeps only the first such lane, so when amb > 0 the caller must
     re-match the batch on a host path to preserve exactness (the
     Router falls back to its trie; no real workload triggers this)."""
+    topics = topics.fields()
     b, max_levels = topics.ids.shape
     c = meta.plen.shape[0]
     tl = topics.lens[:, None]  # [B,1]
@@ -1034,4 +1038,13 @@ def match_ids_hash(
     ti = jnp.where(ok, topic_of_pair, -1).astype(jnp.int32)
     bi = jnp.where(ok, g_bkt, -1).astype(jnp.int32)
     amb = ((nmatch > 1) | (pvalid & (nbm > 2))).sum(dtype=jnp.int32)
-    return ti, bi, total, amb
+    return jnp.concatenate([ti, bi, total[None], amb[None]])
+
+
+def split_hash_result(out, max_hits: int):
+    """(topic_idx [max_hits], bucket_id [max_hits], total, amb): views
+    of `match_ids_hash`'s one result buffer (numpy or jax)."""
+    return (
+        out[:max_hits], out[max_hits : 2 * max_hits],
+        out[2 * max_hits], out[2 * max_hits + 1],
+    )

@@ -44,11 +44,40 @@ from .vocab import PLUS, Vocab
 
 
 class EncodedTopics(NamedTuple):
-    """A batch of inbound topic names, dictionary-encoded."""
+    """A batch of inbound topic names, dictionary-encoded, one array
+    per field: the input of the dense and mesh kernels, which take
+    their three leaves as host views of a `PackedTopics` buffer."""
 
     ids: np.ndarray  # int32 [B, L]  (first L levels; OOV beyond vocab)
     lens: np.ndarray  # int32 [B]    (TRUE level count, may exceed L)
     dollar: np.ndarray  # bool [B]   (first level starts with '$')
+
+
+class PackedTopics(NamedTuple):
+    """The same batch in ONE int32 [B, L + 2] buffer, so a launch moves
+    one host->device buffer instead of three: columns 0..L-1 hold the
+    level ids, column L the true level count, column L + 1 the '$'
+    flag. What `encode_topics` returns and the hash kernel takes; the
+    field keeps the name `ids`, so the kernel's operand stays
+    `topics_ids`."""
+
+    ids: np.ndarray  # int32 [B, L + 2]
+
+    def fields(self) -> EncodedTopics:
+        """The three-field form: views of the buffer (numpy or jax)."""
+        lv = self.ids.shape[-1] - 2
+        return EncodedTopics(
+            self.ids[..., :lv], self.ids[..., lv], self.ids[..., lv + 1] != 0
+        )
+
+    @classmethod
+    def of(cls, enc: EncodedTopics) -> "PackedTopics":
+        """Pack a batch encoded elsewhere (jax arrays, e.g. generated
+        on the device): the inverse of `fields`."""
+        return cls(jnp.concatenate(
+            [enc.ids, enc.lens[..., None], enc.dollar[..., None].astype(jnp.int32)],
+            axis=-1,
+        ).astype(jnp.int32))
 
 
 def encode_topics(
@@ -56,11 +85,12 @@ def encode_topics(
     topics: Sequence[str],
     max_levels: int,
     pad_to: int = 0,
-) -> EncodedTopics:
-    """Encode topic names for the kernel. Topics deeper than max_levels
-    are still matched correctly against any representable filter: only
-    the first `plen <= max_levels` levels are ever compared, and the
-    true length is kept for the exact/'#' length checks.
+) -> PackedTopics:
+    """Encode topic names for the kernels into one packed buffer.
+    Topics deeper than max_levels are still matched correctly against
+    any representable filter: only the first `plen <= max_levels`
+    levels are ever compared, and the true length is kept for the
+    exact/'#' length checks.
 
     `pad_to` (when > len(topics)) grows the batch axis with INERT
     rows — zero levels, $-rooted — that match no representable filter
@@ -70,19 +100,18 @@ def encode_topics(
     result rows with topic index >= len(topics), the same guard as
     mesh dp padding."""
     b = max(len(topics), pad_to)
-    ids = np.zeros((b, max_levels), np.int32)
-    lens = np.zeros(b, np.int32)
-    dollar = np.zeros(b, bool)
+    buf = np.zeros((b, max_levels + 2), np.int32)
     if pad_to > len(topics):
-        dollar[len(topics):] = True
+        buf[len(topics):, max_levels + 1] = 1
     lk = vocab.lookup
     for i, t in enumerate(topics):
         ws = t.split("/")
-        lens[i] = len(ws)
-        dollar[i] = ws[0].startswith("$")
+        row = buf[i]
+        row[max_levels] = len(ws)
+        row[max_levels + 1] = ws[0].startswith("$")
         for j, w in enumerate(ws[:max_levels]):
-            ids[i, j] = lk(w)
-    return EncodedTopics(ids, lens, dollar)
+            row[j] = lk(w)
+    return PackedTopics(buf)
 
 
 def _match_block(

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..obs.profiler import STAGE_MARK
-from ..ops.match import EncodedTopics, _match_block, _pack_bits
+from ..ops.match import EncodedTopics, PackedTopics, _match_block, _pack_bits
 from ..ops.table import EncodedFilters
 from .mesh import DP_AXIS, SUB_AXIS, filter_sharding, topic_sharding
 
@@ -1093,7 +1093,7 @@ class ShardedDeviceTable:
             mh = 1 << (cap.bit_length() - 1)
         return max(mh, self._mh_floor)
 
-    def match_ids_begin(self, enc: EncodedTopics, residual: bool = False):
+    def match_ids_begin(self, enc: PackedTopics, residual: bool = False):
         """Launch the sharded dense compaction kernel WITHOUT forcing
         any device->host transfer AND begin the result copy
         (ops/transfer.FetchTicket, handle's last element — the same
@@ -1104,6 +1104,7 @@ class ShardedDeviceTable:
         if self.degraded:
             return ("1dev",) + self._single.match_ids_begin(enc, residual)
         assert self._dev is not None, "sync() before matching"
+        enc = enc.fields()
         dev = self._dev
         if residual:
             assert self._dev_residual is not None
@@ -1129,6 +1130,7 @@ class ShardedDeviceTable:
         prev = STAGE_MARK.enter("ticket_start")
         ticket = transfer_ops.start_fetch(out, self.telemetry)
         STAGE_MARK.leave(prev)
+        self.telemetry.count("transfer_buffers_total", len(t_dev) + len(out))
         if rec is not None:
             sc.attach(rec, ticket)
         return (dev, t_dev, mh, rec, ticket)
@@ -1155,7 +1157,9 @@ class ShardedDeviceTable:
                 "mesh_match_ids", (int(t_dev.ids.shape[0]), mh)
             )
             self._mh_floor = max(self._mh_floor, mh)
-            ti, ri, totals = self._match_kernel(mh)(dev, t_dev)
+            out = self._match_kernel(mh)(dev, t_dev)
+            tel.count("transfer_buffers_total", len(out))
+            ti, ri, totals = out
             totals = np.asarray(totals)
         ti = np.asarray(ti).reshape(-1)
         ri = np.asarray(ri).reshape(-1)
@@ -1179,7 +1183,7 @@ class ShardedDeviceTable:
             )
         return ti[keep], ri[keep]
 
-    def match_ids(self, enc: EncodedTopics, residual: bool = False):
+    def match_ids(self, enc: PackedTopics, residual: bool = False):
         """All (topic, row) hit pairs for an encoded topic batch via
         the dense kernel. With residual=True the active mask narrows
         to the class index's residual rows (the unclassed fallback).
@@ -1188,7 +1192,7 @@ class ShardedDeviceTable:
         Composed from the begin/finish pipeline halves."""
         return self.match_ids_finish(self.match_ids_begin(enc, residual))
 
-    def match_hash_begin(self, enc: EncodedTopics):
+    def match_hash_begin(self, enc: PackedTopics):
         """Launch the mesh-sharded production hash kernel without a
         host fetch AND begin the result transfer (ticket last, same
         contract as DeviceTable.match_hash_begin). Returns an opaque
@@ -1196,6 +1200,7 @@ class ShardedDeviceTable:
         if self.degraded:
             return ("1dev",) + self._single.match_hash_begin(enc)
         assert self._dev_slots is not None, "sync() before matching"
+        enc = enc.fields()
         sc = self.scope
         rec = None
         if sc is not None:
@@ -1217,6 +1222,7 @@ class ShardedDeviceTable:
         prev = STAGE_MARK.enter("ticket_start")
         ticket = transfer_ops.start_fetch(out, self.telemetry)
         STAGE_MARK.leave(prev)
+        self.telemetry.count("transfer_buffers_total", len(t_dev) + len(out))
         if rec is not None:
             sc.attach(rec, ticket)
         return (t_dev, mh, rec, ticket)
@@ -1242,9 +1248,9 @@ class ShardedDeviceTable:
                 "mesh_match_ids_hash", (int(t_dev.ids.shape[0]), mh)
             )
             self._mh_floor = max(self._mh_floor, mh)
-            ti, bi, totals, amb = self._hash_kernel(mh)(
-                self._dev_meta, self._dev_slots, t_dev
-            )
+            out = self._hash_kernel(mh)(self._dev_meta, self._dev_slots, t_dev)
+            tel.count("transfer_buffers_total", len(out))
+            ti, bi, totals, amb = out
             totals = np.asarray(totals)
         ti = np.asarray(ti).reshape(-1)
         bi = np.asarray(bi).reshape(-1)
@@ -1264,7 +1270,7 @@ class ShardedDeviceTable:
             )
         return ti[keep], bi[keep], int(np.asarray(amb).reshape(-1)[0])
 
-    def match_hash(self, enc: EncodedTopics):
+    def match_hash(self, enc: PackedTopics):
         """(topic, bucket) candidates via the mesh-sharded production
         hash kernel. Returns (ti 1d, bi 1d, amb int): global topic
         indices (may include dp-padding rows — callers drop
@@ -1369,7 +1375,7 @@ class ShardedDeviceTable:
             warmed += 1
         return warmed
 
-    def warmup_escalated(self, enc: EncodedTopics) -> int:
+    def warmup_escalated(self, enc: PackedTopics) -> int:
         """Pre-build the first escalation step (2x the current block
         capacity) for both match kernels at this batch shape: a
         serve-time overflow then re-dispatches against a warm cache
@@ -1379,7 +1385,7 @@ class ShardedDeviceTable:
         blocking fetch on this path)."""
         if self.degraded or self._dev is None:
             return 0
-        t_dev = self._mesh_mod.put_topics(enc, self.mesh)
+        t_dev = self._mesh_mod.put_topics(enc.fields(), self.mesh)
         b = int(t_dev.ids.shape[0])
         mh2 = self._block_mh() * 2
         warmed = 0

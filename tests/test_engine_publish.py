@@ -357,6 +357,35 @@ async def test_engine_rewarms_a_grown_table_off_the_loop():
     _gc.unfreeze()
 
 
+async def test_served_hash_batch_moves_one_buffer_each_way():
+    # one engine batch on the hash leg crosses the host-device link
+    # twice: the packed topics in, the packed result out (the
+    # three-field layout moved 3 + 4)
+    from emqx_tpu.broker.message import Message
+    from emqx_tpu.broker.pubsub import Broker
+    from emqx_tpu.broker.session import SessionConfig
+
+    b = Broker()
+    eng = b.enable_dispatch_engine(queue_depth=8, deadline_ms=0.5)
+    sess, _ = b.open_session("c", clean_start=True, cfg=SessionConfig())
+    got = []
+    sess.outgoing_sink = got.extend
+    for i in range(16):
+        b.subscribe(sess, f"k{i}/+/v/#", SubOpts(qos=0))
+    eng.warmup()
+    c = b.router.telemetry.counters
+    batches0 = c.get("dispatch_batches_total", 0)
+    moved0 = c.get("transfer_buffers_total", 0)
+    assert await eng.publish(Message(topic="k3/a/v/w", payload=b"p")) == 1
+    assert c["dispatch_batches_total"] - batches0 == 1
+    assert c["transfer_buffers_total"] - moved0 == 2
+    assert len(got) == 1
+    await eng.stop()
+    import gc as _gc
+
+    _gc.unfreeze()
+
+
 def test_host_trie_replays_in_steps_and_in_order():
     from emqx_tpu.models.router import Router
 

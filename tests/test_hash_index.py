@@ -9,6 +9,7 @@ expansion, exercised both directly and through Router.match_batch.
 import random
 
 import numpy as np
+import pytest
 
 from emqx_tpu.models.router import Router
 from emqx_tpu.ops import hash_index as H
@@ -40,7 +41,9 @@ def hash_match_rows(table, ix, topics, max_hits=4096):
     enc = M.encode_topics(table.vocab, topics, table.max_levels)
     meta = H.ClassMeta(*(np.array(a) for a in ix.meta))
     slots = H.SlotArrays(*(np.array(a) for a in ix.slots))
-    ti, bi, total, amb = H.match_ids_hash(meta, slots, enc, max_hits=max_hits)
+    ti, bi, total, amb = H.split_hash_result(
+        np.asarray(H.match_ids_hash(meta, slots, enc, max_hits=max_hits)), max_hits
+    )
     total = int(total)
     assert int(amb) == 0, "full-fingerprint collision in a test table"
     assert total <= max_hits, "test tables must fit the bound"
@@ -239,7 +242,9 @@ def test_hash_host_device_agreement():
     enc = M.encode_topics(table.vocab, ["dev/a/room/1"], table.max_levels)
     meta = H.ClassMeta(*(np.array(a) for a in ix.meta))
     slots = H.SlotArrays(*(np.array(a) for a in ix.slots))
-    ti, bi, total, _amb = H.match_ids_hash(meta, slots, enc, max_hits=64)
+    ti, bi, total, _amb = H.split_hash_result(
+        np.asarray(H.match_ids_hash(meta, slots, enc, max_hits=64)), 64
+    )
     # both pairs must be found via their stored (h1, fp)
     assert int(total) == 2
 
@@ -297,3 +302,130 @@ def test_amb_collision_falls_back_to_host_exactly():
     # dest resolution stays exact too
     assert r.match_routes("col/9/x") == {"nodeA"}
     assert r.match_routes("col/9/y") == {"nodeB"}
+
+
+# --- the packed launch: one buffer in, one buffer out ---------------------------
+
+
+def _random_router(rng):
+    r = Router(max_levels=6)
+    routes = []
+    for i in range(300):
+        f = random_filter(rng)
+        d = f"n{i % 7}"
+        r.add_route(f, d)
+        routes.append((f, d))
+    return r, routes
+
+
+def _case_batch(b):
+    """`b` is the pow2 batch the launch pads to: b // 2 + 1 live topics
+    (b itself at 1 and 2), so every batch above 2 carries padding rows."""
+
+    def build():
+        rng = random.Random(b)
+        r, routes = _random_router(rng)
+        n = b if b <= 2 else b // 2 + 1
+        return r, routes, [random_topic(rng) for _ in range(n)]
+
+    return build
+
+
+def _case_dollar():
+    r = Router(max_levels=6)
+    routes = [("#", "n1"), ("+/a/#", "n2"), ("$SYS/#", "n3"),
+              ("$SYS/+/b", "n4"), ("+/+/b", "n5"), ("$x/a", "n6")]
+    for f, d in routes:
+        r.add_route(f, d)
+    topics = ["$SYS/a/b", "$SYS/x", "$x/a", "sys/a/b", "$SYS", "q/a"]
+    return r, routes, topics
+
+
+def _case_deep():
+    r = Router(max_levels=4)
+    routes = [("a/#", "n1"), ("a/+/c/#", "n2"), ("+/+/+/+", "n3"),
+              ("a/b/c/d", "n4"), ("+/b/#", "n5")]
+    for f, d in routes:
+        r.add_route(f, d)
+    topics = ["a/b/c/d/e/f", "a/b/c/d", "a/x/c/d/e", "z/b/c/d/e/f/g/h"]
+    return r, routes, topics
+
+
+def _case_overflow():
+    """128 classes (a or + at each of 7 levels, then +) that every topic
+    matches: 16 topics flag 2,048 pairs against the launch's 1,024."""
+    r = Router(max_levels=8)
+    routes = []
+    for m in range(128):
+        f = "/".join("+" if m >> i & 1 else "a" for i in range(7)) + "/+"
+        r.add_route(f, f"n{m}")
+        routes.append((f, f"n{m}"))
+    return r, routes, [f"a/a/a/a/a/a/a/x{k}" for k in range(16)]
+
+
+def _case_amb():
+    """A bucket forged into a full fingerprint collision with another
+    (as test_amb_collision_falls_back_to_host_exactly plants it)."""
+    r = Router(max_levels=8)
+    routes = [("col/+/x", "nodeA"), ("col/+/y", "nodeB"), ("other/t", "nodeC")]
+    for f, d in routes:
+        r.add_route(f, d)
+    ix = r.index
+    bid_a = ix._row_bucket[r._filter_row["col/+/x"]]
+    bid_b = ix._row_bucket[r._filter_row["col/+/y"]]
+    ix._bkt_h1[bid_b] = ix._bkt_h1[bid_a]
+    ix._bkt_fp[bid_b] = ix._bkt_fp[bid_a]
+    ix._rebuild(ix.n_buckets)
+    return r, routes, ["col/9/x", "col/9/y", "other/t", "col/9/z", "miss/x"]
+
+
+PACKED_CASES = {
+    **{f"b{b}": _case_batch(b) for b in (1, 2, 4, 8, 16, 32, 64)},
+    "dollar": _case_dollar,
+    "deep": _case_deep,
+    "overflow": _case_overflow,
+    "amb": _case_amb,
+}
+
+
+@pytest.mark.parametrize("case", list(PACKED_CASES))
+def test_packed_launch_matches_host_trie(case):
+    """The hash leg's one-buffer launch gives what the host trie gives,
+    topic for topic, and the packed row holds each topic's true level
+    count and '$' flag."""
+    r, routes, topics = PACKED_CASES[case]()
+    tel = r.telemetry
+    p = r.match_filters_begin(topics)
+    got = r.match_filters_finish(p)
+    assert p.mode == "hash" and p.hash_pending is not None
+    b = 1 << (len(topics) - 1).bit_length()
+    lv = r.max_levels
+    assert p.enc.ids.shape == (b, lv + 2) and p.enc.ids.dtype == np.int32
+    for i, t in enumerate(topics):
+        assert sorted(got[i]) == sorted(r.match_filters(t)), t
+        assert {d for f in got[i] for d in r.filter_dests(f)} == oracle_dests(routes, t), t
+        assert p.enc.ids[i, lv] == len(t.split("/"))
+        assert p.enc.ids[i, lv + 1] == t.startswith("$")
+    # padding rows: zero levels, '$'-rooted
+    assert not p.enc.ids[len(topics):, : lv + 1].any()
+    assert p.enc.ids[len(topics):, lv + 1].all()
+    # the escalation re-run and the host fallback engage where planted
+    retries = tel.counters.get("hash_overflow_retries_total", 0)
+    fallbacks = tel.counters.get("ambiguous_batches_total", 0)
+    assert retries == (1 if case == "overflow" else 0)
+    assert fallbacks == (1 if case == "amb" else 0)
+
+
+def test_warmed_pow2_batches_serve_without_new_shapes():
+    """After warmup_shapes(64) a served batch of each pow2 size records no
+    new shape key: the packed kernel is warm at every batch it can get."""
+    rng = random.Random(3)
+    r, _routes = _random_router(rng)
+    r.warmup_shapes(64)
+    tel = r.telemetry
+    tel.mark_serving()
+    buckets = tel.shape_buckets()
+    for b in (1, 2, 4, 8, 16, 32, 64):
+        r.match_filters_batch([random_topic(rng) for _ in range(b)])
+    assert tel.shape_buckets() == buckets
+    assert tel.counters["recompiles_at_serve_total"] == 0

@@ -34,7 +34,7 @@ def random_topic(rng, max_levels=7, vocab=("a", "b", "c", "dev", "", "zz")):
 
 
 def assert_kernel_matches_oracle(table, topics):
-    enc_t = M.encode_topics(table.vocab, topics, table.max_levels)
+    enc_t = M.encode_topics(table.vocab, topics, table.max_levels).fields()
     filters = table.snapshot()
     dense = np.asarray(M.match_dense(filters, enc_t))
     packed = np.asarray(M.match_packed(filters, enc_t, chunk=256))
@@ -139,7 +139,7 @@ def test_match_ids_compaction():
     for _ in range(300):
         table.add(random_filter(rng))
     topics = [random_topic(rng) for _ in range(40)]
-    enc_t = M.encode_topics(table.vocab, topics, table.max_levels)
+    enc_t = M.encode_topics(table.vocab, topics, table.max_levels).fields()
     filters = table.snapshot()
     expected = M.oracle_match_rows(table, topics)
     ti, ri, total = (np.asarray(a) for a in M.match_ids(filters, enc_t, max_hits=4096, chunk=256))
@@ -159,7 +159,7 @@ def test_match_ids_overflow_bound():
     table = FilterTable(max_levels=4, capacity=1024)
     for _ in range(100):
         table.add("#")  # every topic matches all 100
-    enc_t = M.encode_topics(table.vocab, ["a"] * 8, table.max_levels)
+    enc_t = M.encode_topics(table.vocab, ["a"] * 8, table.max_levels).fields()
     ti, ri, total = M.match_ids(table.snapshot(), enc_t, max_hits=64, chunk=256)
     assert int(total) == 800 > 64  # overflow signalled, caller falls back
 
@@ -170,7 +170,7 @@ def test_packed_equals_dense_large():
     for _ in range(1500):
         table.add(random_filter(rng))
     topics = [random_topic(rng) for _ in range(33)]
-    enc_t = M.encode_topics(table.vocab, topics, table.max_levels)
+    enc_t = M.encode_topics(table.vocab, topics, table.max_levels).fields()
     filters = table.snapshot()
     dense = np.asarray(M.match_dense(filters, enc_t))
     packed = np.asarray(M.match_packed(filters, enc_t, chunk=512))

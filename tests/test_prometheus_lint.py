@@ -484,6 +484,22 @@ async def test_transfer_and_warmup_families_lint():
         assert types.get(fam) == kind, f"{fam}: {types.get(fam)}"
 
 
+def test_transfer_buffers_family_lint():
+    """`emqx_xla_transfer_buffers_total` (buffers the match launches moved
+    across the host-device link) rides the scrape as a counter: two per
+    hash batch, the packed topics in and the packed result out."""
+    broker = _scraped_broker()
+    tel = broker.router.telemetry
+    assert tel.counters["dispatch_batches_total"] >= 1
+    assert tel.counters["transfer_buffers_total"] == (
+        2 * tel.counters["dispatch_batches_total"]
+    )
+    text = prometheus_text(broker, "n1@host")
+    assert _lint(text).get("emqx_xla_transfer_buffers_total") == "counter"
+    n = tel.counters["transfer_buffers_total"]
+    assert f'emqx_xla_transfer_buffers_total{{node="n1@host"}} {n}' in text
+
+
 def test_shard_fault_and_failover_families_lint():
     """ISSUE-11 families: the shard-scoped injector's LABELED counter
     (emqx_xla_fault_injected_total{leg,shard}) and the shard

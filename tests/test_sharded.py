@@ -35,7 +35,7 @@ def build_table(n=64):
 def test_sharded_counts_and_packed_match_host(mesh8):
     table, _rows = build_table()
     topics = [f"a/{i}/x" for i in range(20)] + ["$SYS/y", "b", "a"]
-    enc = M.encode_topics(table.vocab, topics, table.max_levels)
+    enc = M.encode_topics(table.vocab, topics, table.max_levels).fields()
 
     match_counts, match_packed, _ = make_sharded_kernels(mesh8)
     f_dev = mesh_mod.put_filters(table.snapshot(), mesh8)
@@ -76,7 +76,7 @@ def test_sharded_apply_delta(mesh8):
     )
 
     topics = ["a/0/x", "b/z", "a/5/x"]
-    enc = M.encode_topics(table.vocab, topics, table.max_levels)
+    enc = M.encode_topics(table.vocab, topics, table.max_levels).fields()
     t_dev = mesh_mod.put_topics(enc, mesh8)
     counts = np.asarray(match_counts(f_dev, t_dev))[: len(topics)]
     expected = M.oracle_match_rows(table, topics)
@@ -98,7 +98,7 @@ def test_mesh_defaults():
 def test_topic_padding(mesh8):
     table, _ = build_table(8)
     topics = ["a/1/x", "a/2/x", "a/3/x"]  # 3 does not divide dp=2
-    enc = M.encode_topics(table.vocab, topics, table.max_levels)
+    enc = M.encode_topics(table.vocab, topics, table.max_levels).fields()
     t_dev = mesh_mod.put_topics(enc, mesh8)
     assert t_dev.ids.shape[0] == 4
     match_counts, _, _ = make_sharded_kernels(mesh8)

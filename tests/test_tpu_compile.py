@@ -12,6 +12,7 @@ every test file.
 
 import functools
 import os
+import re
 
 import numpy as np
 import pytest
@@ -103,6 +104,12 @@ def topic_shapes(levels, ids_sh, row_sh):
     )
 
 
+def packed_shapes(levels, sh):
+    from emqx_tpu.ops.match import PackedTopics
+
+    return PackedTopics(sds((BATCH, levels + 2), jnp.int32, sh))
+
+
 def filter_shapes(levels, words_sh, row_sh):
     from emqx_tpu.ops.table import EncodedFilters
 
@@ -131,10 +138,15 @@ def test_match_ids_hash(one_chip, levels):
     compiled = match_ids_hash.lower(
         meta_shapes(one_chip),
         slot_shapes(one_chip),
-        topic_shapes(levels, one_chip, one_chip),
+        packed_shapes(levels, one_chip),
         max_hits=MAX_HITS,
     ).compile()
     assert_fits(compiled)
+    # one packed topics operand, still named topics_ids (the benchmark's
+    # kernel reader reads B and L from it)
+    text = compiled.as_text()
+    assert re.search(rf"topics_ids(\.\d+)?: s32\[{BATCH},{levels + 2}\]", text)
+    assert "topics_lens" not in text and "topics_dollar" not in text
 
 
 def test_resolve_fanout_100k(one_chip):
