@@ -1887,6 +1887,7 @@ class Router:
         if p.mode == "hash":
             ix = self.index
             host_fallback = False
+            device_pairs = 0  # verified (topic, filter) pairs of the device legs
             if p.hash_pending is not None:
                 t0 = clock()
                 ti, bi, amb = self.device_table.match_hash_finish(
@@ -1917,6 +1918,7 @@ class Router:
                         if topic_mod.match(twords[t_idx], fw):
                             for row in ix.bucket_rows(bid):
                                 out[t_idx].append(self._row_filter[row])
+                                device_pairs += 1
                     tel.record_dispatch(LEG_UNPACK, clock() - t0)
             if host_fallback:
                 tel.count("host_fallback_total")
@@ -1938,9 +1940,17 @@ class Router:
                 for t_idx, row in zip(ti, ri):
                     if t_idx < b:  # drop pow2/dp padding rows
                         out[int(t_idx)].append(self._row_filter[int(row)])
+                        device_pairs += 1
                 tel.record_dispatch(
                     LEG_DENSE, p.residual_elapsed + clock() - t0
                 )
+            if not host_fallback and (
+                p.hash_pending is not None or p.residual_pending is not None
+            ):
+                # topics the device answered and the pairs it gave them
+                # (cache hits and host-trie batches are in neither)
+                tel.count("match_device_topics_total", len(topics))
+                tel.count("match_device_pairs_total", device_pairs)
         elif p.mode == "dense":
             t0 = clock()
             ti, ri = self.device_table.match_ids_finish(p.dense_pending)

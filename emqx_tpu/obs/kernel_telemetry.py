@@ -30,7 +30,11 @@ path reports into:
   * `transfer_buffers_total`: buffers that crossed the host-device
     link for match launches, both directions (host arrays a kernel
     takes + result buffers it returns). A hash batch moves 2: the
-    packed topics in, the packed result out.
+    packed topics in, the packed result out;
+  * `match_device_topics_total` / `match_device_pairs_total`: topics
+    the device hash leg answered (not cache hits, not batches re-matched
+    on the host trie) and the verified (topic, filter) pairs it gave
+    them, so their ratio is the matched filters per topic.
 
 Export surfaces: `prometheus_lines()` renders `emqx_xla_*` families
 (histograms with `_bucket`/`_sum`/`_count` + `le` labels) appended to

@@ -998,13 +998,16 @@ def match_ids_hash(
     # artifacts drop out here). Gathering all 2W=8 lanes' full
     # fingerprints cost 8 sparse HBM reads per pair and was ~85% of
     # kernel time at C=1 (measured r5); instead verify only the FIRST
-    # TWO byte-matching lanes (3 sparse reads: 2 fp + 1 bucket id).
-    # Exactness: the true lane always byte-matches, so with <=2
-    # byte-matching lanes the two verified lanes cover every possible
-    # match; pairs with >2 byte-matching lanes (P ~ C(7,2)/255^2 ~
-    # 1e-4 per flagged pair, adversarial tables included) are counted
-    # into `amb`, which already routes the batch to the exact host
-    # matcher. Empty/deleted slots hold probe byte 0 and never match.
+    # THREE byte-matching lanes (4 sparse reads: 3 fp + 1 bucket id).
+    # Exactness: the true lane always byte-matches, so with <=3
+    # byte-matching lanes the three verified lanes cover every possible
+    # match; pairs with >3 byte-matching lanes (P ~ C(7,3) (f/255)^3,
+    # ~2e-7 per true pair at the 48% fill f a doubled table sits at)
+    # are counted into `amb`, which routes the batch to the exact host
+    # matcher. Verifying only two lanes (>2 byte-matching lanes to
+    # `amb`, ~7e-5 per true pair) sends ~1.3% of 64-topic batches to
+    # the host at 2.9 matched filters a topic. Empty/deleted slots hold
+    # probe byte 0 and never match.
     pw1 = w1.reshape(-1)[psafe]  # [H] probe words (small-array gathers)
     pw2 = w2.reshape(-1)[psafe]
     pp8 = jnp.maximum(pfp >> jnp.uint32(24), jnp.uint32(1))  # [H]
@@ -1019,25 +1022,32 @@ def match_ids_hash(
     l1 = jnp.argmax(bm, axis=1)  # first byte-matching lane
     bm2 = bm & (jnp.arange(2 * BUCKET_W)[None, :] != l1[:, None])
     l2 = jnp.argmax(bm2, axis=1)  # second (== 0 when absent; gated)
+    bm3 = bm2 & (jnp.arange(2 * BUCKET_W)[None, :] != l2[:, None])
+    l3 = jnp.argmax(bm3, axis=1)  # third (gated the same way)
     lslot_of = lambda ln: (  # noqa: E731 — local index helper
         jnp.where(ln < BUCKET_W, pb1, pb2) * jnp.uint32(BUCKET_W)
         + (ln.astype(jnp.uint32) & jnp.uint32(BUCKET_W - 1))
     ).astype(jnp.int32)
     s1 = lslot_of(l1)
     s2 = lslot_of(l2)
+    s3 = lslot_of(l3)
     f1 = slots.fp[s1]  # [H] sparse
     f2 = slots.fp[s2]  # [H] sparse
+    f3 = slots.fp[s3]  # [H] sparse
     ok1 = (nbm >= 1) & (f1 == pfp)
     ok2 = (nbm >= 2) & (f2 == pfp)
-    nmatch = ok1.astype(jnp.int32) + ok2.astype(jnp.int32)
+    ok3 = (nbm >= 3) & (f3 == pfp)
+    nmatch = (
+        ok1.astype(jnp.int32) + ok2.astype(jnp.int32) + ok3.astype(jnp.int32)
+    )
     found = nmatch > 0
-    win_slot = jnp.where(ok1, s1, s2)
+    win_slot = jnp.where(ok1, s1, jnp.where(ok2, s2, s3))
     g_bkt = slots.bucket[win_slot]  # [H] — one sparse gather per pair
     ok = found & (g_bkt >= 0)
     topic_of_pair = (pflat // c).astype(jnp.int32)
     ti = jnp.where(ok, topic_of_pair, -1).astype(jnp.int32)
     bi = jnp.where(ok, g_bkt, -1).astype(jnp.int32)
-    amb = ((nmatch > 1) | (pvalid & (nbm > 2))).sum(dtype=jnp.int32)
+    amb = ((nmatch > 1) | (pvalid & (nbm > 3))).sum(dtype=jnp.int32)
     return jnp.concatenate([ti, bi, total[None], amb[None]])
 
 

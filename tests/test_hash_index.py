@@ -69,6 +69,52 @@ def assert_hash_matches_oracle(table, ix, topics):
         )
 
 
+DECOY = 1000  # the bucket id decoy lanes carry (no live bucket has it)
+
+
+def _decoy_slots(ix, bid, decoys):
+    """ix's slots with the key of bucket `bid` moved behind `decoys`
+    lanes whose probe byte equals its own but whose full fingerprint
+    does not, in the kernel's lane order (its first candidate bucket's
+    four lanes, then its alternate's)."""
+    mask = ix.n_buckets - 1
+    h1, fp = int(ix._bkt_h1[bid]), int(ix._bkt_fp[bid])
+    b1 = h1 & mask
+    b2 = b1 ^ ((fp | 1) * H._ALT_MUL & mask)
+    sfp, sbkt, probe = (np.array(a) for a in ix.slots)
+    p8 = max(fp >> 24, 1)
+    key = int(ix._bkt_slot[bid])
+    sfp[key], sbkt[key] = 0, -1
+    probe[key // H.BUCKET_W] &= ~np.uint32(0xFF << 8 * (key % H.BUCKET_W))
+    lanes = [(b, ln) for b in (b1, b2) for ln in range(H.BUCKET_W)]
+    for k, (b, ln) in enumerate(lanes[: decoys + 1]):
+        slot = b * H.BUCKET_W + ln
+        assert sbkt[slot] < 0, "the lanes before the key must be free"
+        sfp[slot] = fp if k == decoys else fp ^ 1
+        sbkt[slot] = bid if k == decoys else DECOY
+        probe[b] |= np.uint32(p8 << 8 * ln)
+    return H.SlotArrays(sfp, sbkt, probe)
+
+
+@pytest.mark.parametrize("decoys, amb", [(0, 0), (1, 0), (2, 0), (3, 1)])
+def test_verify_covers_three_byte_matching_lanes(decoys, amb):
+    """A pair whose key sits behind up to two decoy lanes (three lanes
+    byte-match) is still answered exactly on the device; behind three
+    (four lanes) it goes to `amb`, the host trie's batch."""
+    table, ix, rows = build_indexed(["a/+/c", "x/y"])
+    bid = int(ix._row_bucket[rows[0]])
+    slots = _decoy_slots(ix, bid, decoys)
+    meta = H.ClassMeta(*(np.array(a) for a in ix.meta))
+    enc = M.encode_topics(table.vocab, ["a/b/c", "x/y"], table.max_levels)
+    ti, bi, total, got_amb = H.split_hash_result(
+        np.asarray(H.match_ids_hash(meta, slots, enc, max_hits=64)), 64
+    )
+    assert int(got_amb) == amb
+    hits = {(int(t), int(b)) for t, b in zip(ti[: int(total)], bi[: int(total)]) if b >= 0}
+    if not amb:
+        assert (0, bid) in hits and not any(b == DECOY for _t, b in hits)
+
+
 def test_basic_classes():
     table, ix, _ = build_indexed(
         ["a/b/c", "a/+/c", "a/#", "#", "+/b/#", "$SYS/#", "a//b", "+", "x/y"]

@@ -8,6 +8,8 @@ looks fine in tests and 400s at ingestion."""
 import asyncio
 import re
 
+import pytest
+
 from emqx_tpu.broker.message import Message
 from emqx_tpu.broker.packet import SubOpts
 from emqx_tpu.broker.pubsub import Broker
@@ -498,6 +500,23 @@ def test_transfer_buffers_family_lint():
     assert _lint(text).get("emqx_xla_transfer_buffers_total") == "counter"
     n = tel.counters["transfer_buffers_total"]
     assert f'emqx_xla_transfer_buffers_total{{node="n1@host"}} {n}' in text
+
+
+@pytest.mark.parametrize("name, want", [
+    ("match_device_topics_total", 8),  # the 8 uncached topics of one hash batch
+    ("match_device_pairs_total", 8),  # each matched by k{i}/+/v/# alone
+])
+def test_match_device_pair_families_lint(name, want):
+    """`emqx_xla_match_device_{topics,pairs}_total` (topics the device
+    hash leg answered and the verified pairs it gave them) ride the
+    scrape as counters."""
+    broker = _scraped_broker()
+    tel = broker.router.telemetry
+    assert tel.counters.get("host_fallback_total", 0) == 0
+    assert tel.counters[name] == want
+    text = prometheus_text(broker, "n1@host")
+    assert _lint(text).get(f"emqx_xla_{name}") == "counter"
+    assert f'emqx_xla_{name}{{node="n1@host"}} {want}' in text
 
 
 def test_shard_fault_and_failover_families_lint():
