@@ -1085,7 +1085,15 @@ class Router:
         host-side: a host-resident filter in the set, a fan below
         `min_fan` (host walk is cheaper), an empty fan, or a fan beyond
         the kernel's packing cap — the same escalate-to-host shape as
-        the match path's deep-trie leg."""
+        the match path's deep-trie leg.
+
+        The `min_fan` gate reads the LIVE fan, the sizes of the filters'
+        dest dicts (shared-group dests included), before touching the
+        CSR store: a small-fan set neither rebuilds its pending rows nor
+        reads `fan_of`, and its rows stay pending until a wide resolve
+        needs them. A set whose live fan is below `min_fan` resolves on
+        the host even where its segments, tombstones included, sum above
+        it; the two paths give the same plan."""
         if not filters:
             return None
         if self.device_suspended:
@@ -1106,6 +1114,7 @@ class Router:
                         )
                     return None
         rows = []
+        live = 0
         fr = self._filter_row
         xr = self._exact_row
         for f in filters:
@@ -1116,10 +1125,17 @@ class Router:
                     if self.telemetry.enabled:
                         self.telemetry.count("fanout_host_fallback_total")
                     return None
+                live += len(self._exact[f])
+            else:
+                live += len(self._wild[f])
             rows.append(row)
+        if live < max(min_fan, 1):
+            if self.telemetry.enabled:
+                self.telemetry.count("fanout_small_fan_plans_total")
+            return None
         self._fanout_flush(rows)
         fan = self.dest_store.fan_of(rows)
-        if fan < max(min_fan, 1) or fan > fanout_ops.MAX_FAN:
+        if fan > fanout_ops.MAX_FAN:
             return None
         fi = self.fault_injector
         if fi is not None:

@@ -91,8 +91,10 @@ LEG_SYNC = "sync"  # DeviceTable delta scatter / full upload
 # the standalone family `emqx_xla_fanout_resolve_seconds`
 # (observe_family), plan-cache traffic as the
 # `fanout_plan_{hits,misses,stale}` / `fanout_device_plans_total` /
-# `fanout_host_fallback_total` counters, and the last resolve's
-# fan-to-plan compression as the `fanout_dedup_ratio` gauge.
+# `fanout_host_fallback_total` counters, resolves the live-fan gate
+# sent to the host walk without touching the CSR store as
+# `fanout_small_fan_plans_total`, and the last resolve's fan-to-plan
+# compression as the `fanout_dedup_ratio` gauge.
 #
 # The mesh serve path (parallel/sharded_match.py) likewise: residual
 # wait + host filter of the device-side cross-shard reduction as

@@ -9,6 +9,8 @@ filter B (no global-generation orphaning)."""
 
 import asyncio
 
+import pytest
+
 from emqx_tpu.broker.message import Message
 from emqx_tpu.broker.packet import SubOpts
 from emqx_tpu.broker.pubsub import Broker
@@ -225,6 +227,116 @@ def test_min_fan_and_deep_filters_fall_back_to_host():
     # publishes still deliver through the host walk
     n = b.publish(Message(topic="tiny/1", payload=b"x"))
     assert n == 1
+
+
+def test_small_fan_gate_leaves_dest_store_alone(monkeypatch):
+    # a small-fan table subscribed through the broker (native add_route:
+    # rows only MARKED pending): a window of plan misses resolves on the
+    # host walk without rebuilding a segment or probing fan_of
+    b = Broker()  # default min_fan 1024
+    for k in range(6):
+        for j in range(1 + k % 3):
+            _sub(b, f"c{(k + j) % 6}", f"room/{k}/+", qos=j % 3)
+        _sub(b, f"c{k}", f"room/{k}/t", qos=1)
+    _sub(b, "c0", "room/#", qos=2)
+    _sub(b, "c4", "room/#")
+    r = b.router
+    ds = r.dest_store
+    topics = [f"room/{k}/t" for k in range(6)]
+    keys = [tuple(f for f, _ in r.match_pairs(t)) for t in topics]
+    rows = {r._fanout_row(f) for key in keys for f in key}
+    assert None not in rows and rows <= ds.pending_rows
+    pending0 = set(ds.pending_rows)
+    assert ds.dirty_rows == [] and ds.dirty_edges == []
+    built = []
+    monkeypatch.setattr(ds, "set_row", lambda *a: built.append(a))
+    monkeypatch.setattr(ds, "fan_of", lambda rows: built.append(rows))
+    tel = r.telemetry
+    misses0 = tel.counters.get("fanout_plan_misses", 0)
+    small0 = tel.counters.get("fanout_small_fan_plans_total", 0)
+    # two publishes a topic: the grouped window walk and the single one
+    lives = [Message(topic=t, payload=b"x") for t in topics for _ in (0, 1)]
+    lives.append(Message(topic="room/0/t", payload=b"y"))
+    flists = [list(k) for k in keys for _ in (0, 1)] + [list(keys[0])]
+    results, _meta = b.dispatch_window(lives[:-1], flists[:-1])
+    results += b.dispatch_window(lives[-1:], flists[-1:])[0]
+    assert built == []
+    assert ds.pending_rows == pending0
+    assert ds.dirty_rows == [] and ds.dirty_edges == []
+    misses = tel.counters["fanout_plan_misses"] - misses0
+    assert misses == len(keys)
+    assert tel.counters["fanout_small_fan_plans_total"] - small0 == misses
+    assert tel.counters.get("fanout_device_plans_total", 0) == 0
+    for key, t in zip(keys, topics):
+        pairs = r.match_pairs(t)
+        oracle = b._build_fanout_plan(pairs)
+        assert b._fanout_cache[key][1] == oracle
+    want = [len(b._build_fanout_plan(r.match_pairs(m.topic))[0])
+            for m in lives]
+    assert results == want
+
+
+def _grow_to_min_fan(b, flt, case):
+    """Live fan of (flt, 'g/#') from 2 up to 4 by subscribe,
+    unsubscribe and resubscribe; yields after every mutation with the
+    live fan it left."""
+    s1 = _sub(b, "c1", flt, qos=0)
+    yield 3
+    b.unsubscribe(s1, flt)
+    yield 2
+    b.subscribe(s1, flt, SubOpts(qos=1))
+    yield 3
+    if case == "shared":
+        _sub(b, "m1", f"$share/grp/{flt}")  # a group dest joins the fan
+    else:
+        _sub(b, "c2", "g/#", qos=0)
+    yield 4
+
+
+@pytest.mark.parametrize("case", ["wild", "exact", "shared"])
+def test_growing_fan_crosses_min_fan_gate(case):
+    b = Broker()
+    b._fanout_min_fan = 4
+    flt = "g/1" if case == "exact" else "g/+"
+    _sub(b, "c0", flt, qos=0)
+    _sub(b, "c0", "g/#", qos=2)  # c0's max-QoS winner is the g/# leg
+    r = b.router
+    tel = r.telemetry
+    row = r._fanout_row(flt)
+    assert row in r.dest_store.pending_rows
+
+    def publish():
+        small0 = tel.counters.get("fanout_small_fan_plans_total", 0)
+        dev0 = tel.counters.get("fanout_device_plans_total", 0)
+        pairs = r.match_pairs("g/1")
+        want = b._build_fanout_plan(pairs)
+        n = b.publish(Message(topic="g/1", payload=b"x"))
+        assert b._fanout_cache[tuple(f for f, _ in pairs)][1] == want
+        small = tel.counters.get("fanout_small_fan_plans_total", 0) - small0
+        dev = tel.counters.get("fanout_device_plans_total", 0) - dev0
+        return n, want, small, dev
+
+    n, _want, small, dev = publish()
+    assert (small, dev) == (1, 0) and row in r.dest_store.pending_rows
+    for live in _grow_to_min_fan(b, flt, case):
+        n, want, small, dev = publish()
+        if live < 4:
+            assert (small, dev) == (1, 0)
+            assert row in r.dest_store.pending_rows
+        else:
+            # crossed: the row is rebuilt once and the kernel serves
+            assert (small, dev) == (0, 1)
+            assert row not in r.dest_store.pending_rows
+    mem = want[0]
+    winners = [(c, o.qos) for c, _s, o in mem]
+    if case == "shared":
+        assert winners == [("c0", 2), ("c1", 1)]
+        assert n == 3  # plus one elected group member
+    else:
+        assert winners == [("c0", 2), ("c1", 1), ("c2", 0)]
+        assert n == 3
+    # the launched kernel agrees with the oracle on the same state
+    _assert_identical(b, "g/1")
 
 
 # --- per-filter plan stamps (the regression the ISSUE names) --------------

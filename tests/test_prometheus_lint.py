@@ -247,6 +247,10 @@ def test_fanout_families_lint():
     s.outgoing_sink = lambda pkts: None
     broker.subscribe(s, "fo/#", SubOpts(qos=0))
     broker.publish(Message(topic="fo/1/v", payload=b"x"))  # stale -> device
+    # a small fan below min_fan: the live-fan gate sends it to the host
+    broker._fanout_min_fan = 1024
+    broker.subscribe(s, "sm/+", SubOpts(qos=1))
+    broker.publish(Message(topic="sm/1", payload=b"x"))  # miss -> host
     text = prometheus_text(broker, "n1@host")
     types = _lint(text)
     for fam, kind in (
@@ -254,6 +258,7 @@ def test_fanout_families_lint():
         ("emqx_xla_fanout_plan_misses", "counter"),
         ("emqx_xla_fanout_plan_stale", "counter"),
         ("emqx_xla_fanout_device_plans_total", "counter"),
+        ("emqx_xla_fanout_small_fan_plans_total", "counter"),
         ("emqx_xla_fanout_dedup_ratio", "gauge"),
         ("emqx_xla_fanout_resolve_seconds", "histogram"),
     ):
@@ -270,6 +275,11 @@ def test_fanout_families_lint():
     assert int(count_line.rsplit(" ", 1)[1]) == int(
         plans_line.rsplit(" ", 1)[1]
     ) >= 2
+    small_line = next(
+        l for l in text.splitlines()
+        if l.startswith("emqx_xla_fanout_small_fan_plans_total")
+    )
+    assert int(small_line.rsplit(" ", 1)[1]) == 1
     # dedup ratio reflects the overlapping-filter fan (> 1 client/plan)
     ratio_line = next(
         l for l in text.splitlines()
